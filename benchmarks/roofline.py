@@ -1,9 +1,11 @@
 """Roofline table from the multi-pod dry-run artifacts.
 
-Reads results/dryrun_single_pod.json (written by
-``python -m repro.launch.dryrun --out ...``); if absent, runs a small
-subset in a subprocess (the dry-run must own a fresh process because it
-forces 512 host devices before jax initializes).
+Regenerates its records on every run by running
+``python -m repro.launch.dryrun --out ...`` in a subprocess (the dry-run
+must own a fresh process because it forces 512 host devices before jax
+initializes), so the table is built only from committed code, never from
+a stale artifact. The child is pinned to the CPU: a parent that has run
+tables on a chip holds it, and the dry-run only compiles.
 
 Terms per (arch, shape) on the 16x16 single-pod mesh (TPU v5e constants:
 197 TF/s bf16, 819 GB/s HBM, 50 GB/s/link ICI):
@@ -19,14 +21,10 @@ import os
 import subprocess
 import sys
 
-SINGLE = "results/dryrun_single_pod.json"
 FAST_COMBOS = [("qwen3-0.6b", "train_4k"), ("mamba2-130m", "decode_32k")]
 
 
-def _ensure(fast: bool) -> list[dict]:
-    if os.path.exists(SINGLE):
-        with open(SINGLE) as f:
-            return json.load(f)
+def _generate(fast: bool) -> list[dict]:
     os.makedirs("results", exist_ok=True)
     records = []
     combos = FAST_COMBOS if fast else [("all", "all")]
@@ -35,7 +33,7 @@ def _ensure(fast: bool) -> list[dict]:
         subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
              "--shape", shape, "--out", out],
-            check=True, env={**os.environ,
+            check=True, env={**os.environ, "JAX_PLATFORMS": "cpu",
                              "PYTHONPATH": os.environ.get("PYTHONPATH",
                                                           "src")})
         with open(out) as f:
@@ -44,7 +42,7 @@ def _ensure(fast: bool) -> list[dict]:
 
 
 def run(fast: bool = False):
-    records = _ensure(fast)
+    records = _generate(fast)
     rows, blob = [], {"records": []}
     for r in records:
         if r.get("status") != "ok":

@@ -15,6 +15,8 @@ import json
 import os
 import sys
 
+from repro.compile_cache import use_compile_cache
+
 from . import ablation_fig3, accuracy_table1, async_throughput, \
     comm_table2, dataplane_bench, engine_throughput, microbench, roofline, \
     roundscan, stream_bench, synergy_table3
@@ -42,6 +44,7 @@ def main() -> None:
                     help="1 seed / reduced rounds")
     ap.add_argument("--out-dir", default="results")
     args = ap.parse_args()
+    use_compile_cache()
 
     names = args.tables or list(TABLES)
     os.makedirs(args.out_dir, exist_ok=True)

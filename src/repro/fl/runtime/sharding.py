@@ -30,7 +30,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ...core.strategies import ApplyFn
@@ -103,8 +102,8 @@ def make_sharded_client_fn(apply_fn: ApplyFn, spec, in_axes, mesh: Mesh,
                              else ())
     n = mesh.shape[CLIENT_AXIS]
     in_specs = tuple(P(CLIENT_AXIS) if ax == 0 else P() for ax in axes)
-    mapped = shard_map(vm, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(CLIENT_AXIS), check_rep=False)
+    mapped = jax.shard_map(vm, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(CLIENT_AXIS), check_vma=False)
 
     def padded_call(global_params, data, *rest):
         # pad-to-mesh and slice-back are traced: shapes are static under

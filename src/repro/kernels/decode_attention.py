@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._platform import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -67,7 +69,7 @@ def decode_attention(
     window: int = 0,
     scale: float | None = None,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, _, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
@@ -108,6 +110,6 @@ def decode_attention(
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt, tags, jnp.asarray(index, jnp.int32)[None])
     return jnp.moveaxis(out, 1, 2)                      # (B, 1, H, D)

@@ -10,7 +10,7 @@ with block_q = block_k = 128 and head_dim <= 256 the working set is
 ~(2*128*256 + 128*128) * 4 B < 1 MiB — far inside the ~16 MiB VMEM budget,
 leaving room for double buffering.
 
-Validated against kernels.ref.mha_reference via interpret=True (tests sweep
+Validated against kernels.ref.mha_reference in interpret mode (tests sweep
 shapes, dtypes, GQA ratios, windows).
 """
 from __future__ import annotations
@@ -20,6 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +84,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
@@ -137,5 +139,5 @@ def _call(kernel, qt, kt, vt, b, h, nq, nk, block_q, block_k, d, g,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._platform import resolve_interpret
+
 _LANE = 128
 
 
@@ -69,7 +71,7 @@ def masked_weighted_sum(
     block_p: int = 2048,
     block_m: int | None = None,
     vmem_budget_bytes: int = 4 * 1024 * 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns (P,) = sum_i weights[i] * flat[i, :] in one tiled pass."""
     m, p = flat.shape
@@ -96,6 +98,6 @@ def masked_weighted_sum(
         ],
         out_specs=pl.BlockSpec((bp,), lambda pi, mi: (pi,)),
         out_shape=jax.ShapeDtypeStruct((x.shape[1],), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)
     return out[:p]

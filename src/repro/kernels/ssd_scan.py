@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._platform import resolve_interpret
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
                 h_ref, *, chunk: int, seq_len: int):
@@ -79,7 +81,7 @@ def ssd_chunked(
     *,
     chunk: int = 256,
     init_state=None,     # kernel path requires zero init (assert below)
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     assert init_state is None, "ssd_chunked kernel assumes zero init state"
     bsz, l, h, p = x.shape
@@ -122,7 +124,7 @@ def ssd_chunked(
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xt, dtt, a, bt, ct)
     if pad:
         y = y[:, :, :l, :]

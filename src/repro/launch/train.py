@@ -53,6 +53,7 @@ from ..core.distributed import FedSpec, make_train_step
 from ..data.synthetic import make_token_dataset
 from ..optim import adamw, sgd
 from ..checkpoint import save
+from ..compile_cache import use_compile_cache
 from ..models.api import build_model
 from ..sharding.ctx import use_mesh
 from .mesh import make_host_mesh
@@ -303,8 +304,9 @@ def run_server_engine(args, cfg, model, corpus, client_idx) -> None:
         print("checkpoint:", path)
 
 
-def run_mesh_engine(args, cfg, model, corpus, client_idx) -> None:
-    """Gradient-level rounds: one jitted mesh step, judge traced inside."""
+def run_mesh_engine(args, cfg, model, corpus, client_idx) -> list[dict]:
+    """Gradient-level rounds: one jitted mesh step, judge traced inside.
+    Returns each step's scalar metrics."""
     _, selector, judge = _components(args, host_oracle=False)
     mesh = make_host_mesh()
     m = args.clients
@@ -320,6 +322,7 @@ def run_mesh_engine(args, cfg, model, corpus, client_idx) -> None:
     rng = np.random.default_rng(args.seed)
 
     jitted = jax.jit(step, donate_argnums=(0, 1))
+    records = []
     t0 = time.time()
     with mesh, use_mesh(mesh):
         for it in range(args.steps):
@@ -342,10 +345,13 @@ def run_mesh_engine(args, cfg, model, corpus, client_idx) -> None:
             pos = [sel[i] for i in range(m) if mask[i] > 0]
             neg = [sel[i] for i in range(m) if mask[i] == 0]
             selector.update(pos, neg)
-            print(f"step {it:4d} loss={float(metrics['loss']):.4f} "
-                  f"pos={int(metrics['num_positive'])}/{m} "
-                  f"ent={float(metrics['entropy']):.4f} "
-                  f"gnorm={float(metrics['grad_norm']):.3f}", flush=True)
+            rec = {k: float(metrics[k]) for k in
+                   ("loss", "num_positive", "entropy", "grad_norm")}
+            records.append(rec)
+            print(f"step {it:4d} loss={rec['loss']:.4f} "
+                  f"pos={int(rec['num_positive'])}/{m} "
+                  f"ent={rec['entropy']:.4f} "
+                  f"gnorm={rec['grad_norm']:.3f}", flush=True)
     dt = time.time() - t0
     print(f"done: {args.steps} rounds in {dt:.1f}s "
           f"({dt / args.steps:.2f}s/round); selector={selector.stats()}")
@@ -353,9 +359,12 @@ def run_mesh_engine(args, cfg, model, corpus, client_idx) -> None:
         path = save(args.ckpt_dir, args.steps, params,
                     meta={"arch": cfg.name, "selector": selector.stats()})
         print("checkpoint:", path)
+    return records
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> list[dict] | None:
+    """Parse ``argv`` (default ``sys.argv[1:]``) and train; returns the
+    mesh engine's per-step metrics (``None`` for the server engines)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")
@@ -469,7 +478,8 @@ def main() -> None:
     ap.add_argument("--mesh", default="host")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.reduced:
@@ -510,9 +520,9 @@ def main() -> None:
                 "--num-clusters/--drift-at need a weights-level engine: "
                 "use --engine sequential or pipelined (the server carries "
                 "the ModelBank and applies the drift schedule)")
-        run_mesh_engine(args, cfg, model, corpus, client_idx)
-    else:
-        run_server_engine(args, cfg, model, corpus, client_idx)
+        return run_mesh_engine(args, cfg, model, corpus, client_idx)
+    run_server_engine(args, cfg, model, corpus, client_idx)
+    return None
 
 
 if __name__ == "__main__":

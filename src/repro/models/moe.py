@@ -90,7 +90,6 @@ def moe_block_shard_map(cfg: ModelConfig, p: dict, x: jax.Array,
     kept for comparison (XLA replicates its scatter — see EXPERIMENTS §Perf).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     bsz, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
@@ -153,11 +152,11 @@ def moe_block_shard_map(cfg: ModelConfig, p: dict, x: jax.Array,
     bspec = P(*xspec_dims)
     wspec_in = P("model", batch_axes if batch_axes else None, None)
     wspec_out = P("model", None, batch_axes if batch_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(bspec, P(None, None), wspec_in, wspec_in, wspec_out),
         out_specs=(bspec, P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = fn(x, p["router"]["w"], p["w_in"], p["w_gate"], p["w_out"])
     return out, aux
 

@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode off the TPU) vs
+ref.py oracles."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
