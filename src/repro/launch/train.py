@@ -46,6 +46,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.fl as fl
 from ..configs import ARCHS
@@ -304,6 +305,46 @@ def run_server_engine(args, cfg, model, corpus, client_idx) -> None:
         print("checkpoint:", path)
 
 
+def build_mesh_step(args, model, judge):
+    """The mesh engine's jitted train step (params and optimizer state
+    donated) and its optimizer."""
+    fed = FedSpec(num_clients=args.clients, enabled=not args.no_fedentropy)
+    opt = (sgd(lr=args.lr, momentum=0.5) if args.optimizer == "sgd"
+           else adamw(lr=args.lr))
+    step = make_train_step(model, opt, fed, judge_fn=judge.traced())
+    return jax.jit(step, donate_argnums=(0, 1)), opt
+
+
+def mesh_step_memory(argv: list[str], mesh) -> dict:
+    """``memory_analysis()`` of the mesh train step for ``argv`` (train's
+    flags), compiled for ``mesh`` from abstract shapes: argument, output,
+    alias, temporary and generated-code bytes, and ``total`` = argument
+    + output - alias + temporary, the device memory the step needs."""
+    args = parse_args(list(argv))
+    _, model = build_lm(args)
+    _, _, judge = _components(args, host_oracle=False)
+    jitted, opt = build_mesh_step(args, model, judge)
+    replicated = NamedSharding(mesh, P())
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=replicated), tree)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt_state = abstract(jax.eval_shape(opt.init, params))
+    tokens = jax.ShapeDtypeStruct(
+        (args.clients * args.per_client_batch, args.seq_len + 1),
+        jnp.int32, sharding=replicated)
+    with mesh, use_mesh(mesh):
+        compiled = jitted.lower(abstract(params), opt_state,
+                                {"tokens": tokens}).compile()
+    ma = compiled.memory_analysis()
+    out = {k: int(getattr(ma, f"{k}_size_in_bytes")) for k in
+           ("argument", "output", "alias", "temp", "generated_code")}
+    out["total"] = (out["argument"] + out["output"] - out["alias"]
+                    + out["temp"])
+    return out
+
+
 def run_mesh_engine(args, cfg, model, corpus, client_idx) -> list[dict]:
     """Gradient-level rounds: one jitted mesh step, judge traced inside.
     Returns each step's scalar metrics."""
@@ -311,17 +352,13 @@ def run_mesh_engine(args, cfg, model, corpus, client_idx) -> list[dict]:
     mesh = make_host_mesh()
     m = args.clients
     bsz = m * args.per_client_batch
-    fed = FedSpec(num_clients=m, enabled=not args.no_fedentropy)
-    opt = (sgd(lr=args.lr, momentum=0.5) if args.optimizer == "sgd"
-           else adamw(lr=args.lr))
-    step = make_train_step(model, opt, fed, judge_fn=judge.traced())
+    jitted, opt = build_mesh_step(args, model, judge)
 
     key = jax.random.PRNGKey(args.seed)
     params = model.init(key)
     opt_state = opt.init(params)
     rng = np.random.default_rng(args.seed)
 
-    jitted = jax.jit(step, donate_argnums=(0, 1))
     records = []
     t0 = time.time()
     with mesh, use_mesh(mesh):
@@ -362,9 +399,9 @@ def run_mesh_engine(args, cfg, model, corpus, client_idx) -> list[dict]:
     return records
 
 
-def main(argv: list[str] | None = None) -> list[dict] | None:
-    """Parse ``argv`` (default ``sys.argv[1:]``) and train; returns the
-    mesh engine's per-step metrics (``None`` for the server engines)."""
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """The driver's flags, parsed from ``argv`` (default
+    ``sys.argv[1:]``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")
@@ -478,14 +515,25 @@ def main(argv: list[str] | None = None) -> list[dict] | None:
     ap.add_argument("--mesh", default="host")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
-    use_compile_cache()
+    return ap.parse_args(argv)
 
+
+def build_lm(args):
+    """(config, model) for ``--arch``: f32 params and activations, no
+    remat; ``--reduced`` shrinks the widths."""
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = cfg.reduced()
     cfg = cfg.replace(remat="none", param_dtype="float32", dtype="float32")
-    model = build_model(cfg)
+    return cfg, build_model(cfg)
+
+
+def main(argv: list[str] | None = None) -> list[dict] | None:
+    """Parse ``argv`` (default ``sys.argv[1:]``) and train; returns the
+    mesh engine's per-step metrics (``None`` for the server engines)."""
+    args = parse_args(argv)
+    use_compile_cache()
+    cfg, model = build_lm(args)
 
     corpus, client_idx = build_fl_corpus(
         cfg, args.logical_clients, args.case, args.seq_len, args.seed)

@@ -8,21 +8,26 @@ kernels are called with ``interpret=False`` because this process's
 default backend is the CPU. The topology is described inside a module
 fixture, so only the worker that runs this file loads the TPU compiler.
 """
+import importlib.util
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core.strategies import LocalSpec, client_update
 from repro.kernels.entropy_judge import entropy_judge_sweep
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_aggregate import masked_weighted_sum
+from repro.launch.train import mesh_step_memory
 from repro.models import cnn
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CNN_PARAMS = 62006          # paper CNN at 32x32x3, 10 classes
 QWEN3_06B_PARAMS = 596049920
+V5E_PROGRAM_HBM = int(15.75 * 2**30)    # what XLA:TPU gives one program
 
 
 @pytest.fixture(scope="module")
@@ -88,3 +93,31 @@ def test_cnn_client_update_compiles(one_chip):
     comp = jax.jit(fn).lower(params, data).compile()
     out = comp.out_info
     assert out["soft_label"].shape == (cohort, 10)
+
+
+def _smoke_lm_argv(seq_len):
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    argv = list(smoke.LM_ARGV)
+    argv[argv.index("--seq-len") + 1] = str(seq_len)
+    return argv
+
+
+@pytest.mark.parametrize("seq_len,fits", [(128, True), (256, False)])
+def test_qwen3_mesh_step_memory(one_chip, seq_len, fits):
+    """``chip_smoke.py``'s lm step (qwen3-0.6b at published widths, 4
+    clients x 2 sequences, f32 params + SGD momentum) fits one v5e at
+    seq 128; at seq 256 the compiler refuses it for HBM."""
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    argv = _smoke_lm_argv(seq_len)
+    if fits:
+        mem = mesh_step_memory(argv, mesh)
+        assert mem["argument"] > 4 * QWEN3_06B_PARAMS * 2   # params + mom.
+        assert mem["total"] <= V5E_PROGRAM_HBM
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED"):
+            mesh_step_memory(argv, mesh)
