@@ -1,0 +1,7 @@
+"""Host self time of `fl.select` (the selector's draw) a traced round,
+in ms (bench/spans.py)."""
+from bench import spans
+
+
+def read(ctx: dict):
+    return spans.per_round(ctx, "self_ms", spans.SELECT)

@@ -157,7 +157,7 @@ class ClientCorpus(Mapping):
         self._pad = 0                   # zero rows appended by shard()
         self._hists: dict = {}          # num_classes (or None) -> (N, C)
         self._sizes: np.ndarray | None = None
-        self._gather = jax.jit(self._gather_impl)
+        self._gather = jax.jit(self.cohort_gather)
         self._gather_queued = jax.jit(self._gather_queued_impl)
 
     # ------------------------------------------------------- constructors
@@ -353,7 +353,9 @@ class ClientCorpus(Mapping):
         from jax.sharding import NamedSharding, PartitionSpec as P
         return jax.device_put(v, NamedSharding(self._mesh, P()))
 
-    def _gather_impl(self, arrays: dict, idx: jax.Array) -> dict:
+    def cohort_gather(self, arrays: dict, idx: jax.Array) -> dict:
+        """The gather's traced body; jitted, its program is named
+        ``jit_cohort_gather`` on a trace."""
         out = {k: v[idx] for k, v in arrays.items()}
         if self.transform is not None and "x" in out:
             out["x"] = self.transform(out["x"])
@@ -361,7 +363,7 @@ class ClientCorpus(Mapping):
 
     def _gather_queued_impl(self, arrays: dict, idx: jax.Array,
                             active: jax.Array) -> dict:
-        out = self._gather_impl(arrays, idx)
+        out = self.cohort_gather(arrays, idx)
         if "w" in out:
             s = out["w"].shape[1]
             live = jnp.arange(s)[None, :] < active[:, None]
@@ -376,7 +378,7 @@ class ClientCorpus(Mapping):
         the streaming plane deliberately has no such method (its gather is
         host-side), which is how engines detect a foldable data plane."""
         if active is None:
-            return self._gather_impl(self._arrays, idx)
+            return self.cohort_gather(self._arrays, idx)
         return self._gather_queued_impl(self._arrays, idx, active)
 
     def cohort(self, idx, active=None) -> dict:

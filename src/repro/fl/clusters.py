@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .registry import register
+from .spans import fetch
 
 
 @dataclass(frozen=True)
@@ -151,7 +152,7 @@ class IFCAAssigner:
         data = srv.corpus.cohort(np.asarray(sel))
         scores = self._loss_fn()(bank.stacked, data)
         self.assign_rounds += 1
-        return argmin_assign(scores)
+        return argmin_assign(fetch(scores, np.float64))
 
     def update(self, sel, cluster_ids, out, bank) -> None:
         """IFCA re-assigns from scratch each round; nothing to fold."""
@@ -217,7 +218,7 @@ class FeSEMAssigner:
 
     def update(self, sel, cluster_ids, out, bank: ModelBank) -> None:
         scores = self._dist_fn()(bank.stacked, out["params"])
-        new = argmin_assign(scores)
+        new = argmin_assign(fetch(scores, np.float64))
         idx = np.asarray(sel, np.int64)
         self.reassigned += int(np.sum(self.assignments[idx] != new))
         self.assignments[idx] = new

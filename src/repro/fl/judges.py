@@ -37,17 +37,18 @@ from ..core.judgment import (
     JudgmentResult, judge, judge_budgeted, judge_np,
 )
 from .registry import register
+from .spans import fetch
 
 
 def _result_to_lists(res: JudgmentResult
                      ) -> tuple[list[int], list[int], float]:
-    mask = np.asarray(res.mask)
+    mask = fetch(res.mask)
     accepted = [i for i in range(len(mask)) if mask[i] > 0]
     if res.removal_order is not None:
-        rejected = [int(k) for k in np.asarray(res.removal_order) if k >= 0]
+        rejected = [int(k) for k in fetch(res.removal_order) if k >= 0]
     else:
         rejected = [i for i in range(len(mask)) if mask[i] == 0]
-    return accepted, rejected, float(res.entropy)
+    return accepted, rejected, float(fetch(res.entropy))
 
 
 def _stack_buffer(buffer_soft, buffer_sizes, cand_soft, cand_sizes):

@@ -53,6 +53,7 @@ from ..core.pools import (
 )
 from ..data.corpus import DataQueue
 from .registry import register
+from .spans import fetch
 
 
 def _corpus_histograms(client_data) -> np.ndarray:
@@ -142,7 +143,7 @@ class TracedPoolSelector:
         pos, neg = self._masks()
         sel, self._key = pools_draw(self._key, pos, neg,
                                     num=num, eps=self.eps)
-        chosen = [int(c) for c in np.asarray(sel)]
+        chosen = [int(c) for c in fetch(sel)]
         for c in chosen:            # removed for the round, like DevicePools
             self.positive.discard(c)
             self.negative.discard(c)

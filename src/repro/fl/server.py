@@ -36,10 +36,12 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.aggregation import comm_bytes
 from ..core.strategies import ApplyFn, client_update, cross_entropy
 from ..data.stream import as_data_plane, plane_of
+from . import spans
 from .protocols import Aggregator, ClientStrategy, Judge, Selector
 
 
@@ -126,6 +128,8 @@ def _make_client_fn(apply_fn: ApplyFn, spec, in_axes):
             apply_fn, global_params, data, spec,
             prev_params=prev_p, c_local=c_loc, c_global=c_glob)
 
+    # jitted, the program is ``jit_client_update`` on a profiler trace
+    one.__name__ = one.__qualname__ = "client_update"
     return jax.vmap(one, in_axes=in_axes)
 
 
@@ -283,18 +287,22 @@ class Server:
         dispatch time.
         """
         gp = self.global_params if global_params is None else global_params
-        idx = np.asarray(sel)
-        sched = getattr(selector, "data_schedule", None)
-        active = None if sched is None else sched(sel)
-        data = self.corpus.cohort(idx, active=active)
-        prev_p, c_loc, c_glob = self.strategy.client_inputs(self.state, idx)
-        prep = getattr(self.strategy, "prepare_round", None)
-        if prep is None:
-            return self._client_fn()(gp, data, prev_p, c_loc, c_glob)
-        gdata, aux = prep(data, selector)
-        out = self._client_fn()(gp, gdata, prev_p, c_loc, c_glob,
-                                aux["valid"])
-        return self.strategy.finish_round(out, aux)
+        with TraceAnnotation(spans.STAGE):
+            idx = np.asarray(sel)
+            sched = getattr(selector, "data_schedule", None)
+            active = None if sched is None else sched(sel)
+            data = self.corpus.cohort(idx, active=active)
+            prev_p, c_loc, c_glob = self.strategy.client_inputs(self.state,
+                                                                idx)
+            prep = getattr(self.strategy, "prepare_round", None)
+            if prep is not None:
+                gdata, aux = prep(data, selector)
+        with TraceAnnotation(spans.CLIENTS):
+            if prep is None:
+                return self._client_fn()(gp, data, prev_p, c_loc, c_glob)
+            out = self._client_fn()(gp, gdata, prev_p, c_loc, c_glob,
+                                    aux["valid"])
+            return self.strategy.finish_round(out, aux)
 
     # -------------------------------------------------------------- drift
     def _apply_drift(self) -> list:
@@ -335,7 +343,9 @@ class Server:
         assigned center, gathered off ``bank`` (the server's own unless a
         speculative bank is passed)."""
         bank = self.bank if bank is None else bank
-        return self._run_cohort(sel, selector, bank.gather(cluster_ids))
+        with TraceAnnotation(spans.STAGE):
+            start = bank.gather(cluster_ids)
+        return self._run_cohort(sel, selector, start)
 
     def _judge_clusters(self, soft, sizes, cluster_ids, sel):
         """Per-cluster judgment: the composition's judge runs on each
@@ -374,81 +384,94 @@ class Server:
         """One clustered Alg. 2 round: assign -> per-center ClientUpdate
         -> per-cluster judgment -> per-cluster aggregation -> feedback."""
         cfg = self.config
-        sel = self.selector.select(cfg.cohort_size())
-        idx = np.asarray(sel)
-        cids = self.cluster.assign(sel)
+        with TraceAnnotation(spans.SELECT):
+            sel = self.selector.select(cfg.cohort_size())
+            idx = np.asarray(sel)
+            cids = self.cluster.assign(sel)
         out = self._dispatch_banked(sel, self.selector, cids)
 
-        soft = np.asarray(out["soft_label"], np.float64)
-        sizes = np.asarray(out["size"], np.float64)
-        mask, pos, neg, ent, clusters = self._judge_clusters(
-            soft, sizes, cids, sel)
+        soft = spans.fetch(out["soft_label"], np.float64)
+        sizes = spans.fetch(out["size"], np.float64)
+        with TraceAnnotation(spans.JUDGE):
+            mask, pos, neg, ent, clusters = self._judge_clusters(
+                soft, sizes, cids, sel)
 
-        out_c = dict(out)
-        out_c["cluster"] = jnp.asarray(cids, jnp.int32)
-        new_stacked = self.aggregator(
-            self.bank.stacked, out_c,
-            jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
-        self.state = self.strategy.update_state(
-            self.state, self.bank.stacked, out, idx, cfg.num_clients)
-        # assignment state folds against the PRE-aggregation centers
-        # (verdict-independent — the speculation contract)
-        self.cluster.update(sel, cids, out, self.bank)
-        self.bank = self.bank.replace(new_stacked)
-        self.global_params = self.bank.stacked
-        self.selector.update(pos, neg)
+        with TraceAnnotation(spans.AGGREGATE):
+            out_c = dict(out)
+            out_c["cluster"] = jnp.asarray(cids, jnp.int32)
+            new_stacked = self.aggregator(
+                self.bank.stacked, out_c,
+                jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
+        with TraceAnnotation(spans.FEEDBACK):
+            self.state = self.strategy.update_state(
+                self.state, self.bank.stacked, out, idx, cfg.num_clients)
+            # assignment state folds against the PRE-aggregation centers
+            # (verdict-independent — the speculation contract)
+            self.cluster.update(sel, cids, out, self.bank)
+            self.bank = self.bank.replace(new_stacked)
+            self.global_params = self.bank.stacked
+            self.selector.update(pos, neg)
 
-        # uplink accounting per the paper's model: positives ship ONE
-        # model each (their own center), so the template is a single
-        # center, never the K-stacked bank
-        comm = comm_bytes(self.bank.center(0), len(sel), len(pos),
-                          soft.shape[-1],
-                          control_variate=self.strategy.doubles_uplink)
-        rec = {"round": self.round_idx, "selected": sel, "positive": pos,
-               "negative": neg, "entropy": ent, "comm": comm,
-               "cluster": [int(c) for c in cids], "clusters": clusters}
-        self.history.append(rec)
-        self.round_idx += 1
+            # uplink accounting per the paper's model: positives ship ONE
+            # model each (their own center), so the template is a single
+            # center, never the K-stacked bank
+            comm = comm_bytes(self.bank.center(0), len(sel), len(pos),
+                              soft.shape[-1],
+                              control_variate=self.strategy.doubles_uplink)
+            rec = {"round": self.round_idx, "selected": sel,
+                   "positive": pos, "negative": neg, "entropy": ent,
+                   "comm": comm, "cluster": [int(c) for c in cids],
+                   "clusters": clusters}
+            self.history.append(rec)
+            self.round_idx += 1
         return rec
 
     def round(self) -> dict:
-        """One paper Alg. 2 round; returns the history record."""
-        drifted = self._apply_drift()
-        if self.bank is not None:
-            rec = self._clustered_round()
-            if drifted:
-                rec["drift"] = [list(ev.clients) for ev in drifted]
-            return rec
-        cfg = self.config
-        sel = self.selector.select(cfg.cohort_size())
-        idx = np.asarray(sel)
-        out = self._run_cohort(sel, self.selector)
+        """One paper Alg. 2 round; returns the history record. Its steps
+        run under the profiler spans of :mod:`repro.fl.spans`."""
+        with TraceAnnotation(spans.ROUND, round=self.round_idx):
+            drifted = self._apply_drift()
+            if self.bank is not None:
+                rec = self._clustered_round()
+                if drifted:
+                    rec["drift"] = [list(ev.clients) for ev in drifted]
+                return rec
+            cfg = self.config
+            with TraceAnnotation(spans.SELECT):
+                sel = self.selector.select(cfg.cohort_size())
+                idx = np.asarray(sel)
+            out = self._run_cohort(sel, self.selector)
 
-        soft = np.asarray(out["soft_label"], np.float64)   # (|S_t|, C)
-        sizes = np.asarray(out["size"], np.float64)
+            soft = spans.fetch(out["soft_label"], np.float64)  # (|S_t|, C)
+            sizes = spans.fetch(out["size"], np.float64)
 
-        a_rel, r_rel, ent = self.judge(soft, sizes)
-        mask = np.zeros(len(sel), np.float32)
-        mask[a_rel] = 1.0
+            with TraceAnnotation(spans.JUDGE):
+                a_rel, r_rel, ent = self.judge(soft, sizes)
+                mask = np.zeros(len(sel), np.float32)
+                mask[a_rel] = 1.0
 
-        new_global = self.aggregator(
-            self.global_params, out,
-            jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
-        self.state = self.strategy.update_state(
-            self.state, self.global_params, out, idx, cfg.num_clients)
-        self.global_params = new_global
+            with TraceAnnotation(spans.AGGREGATE):
+                new_global = self.aggregator(
+                    self.global_params, out,
+                    jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
+            with TraceAnnotation(spans.FEEDBACK):
+                self.state = self.strategy.update_state(
+                    self.state, self.global_params, out, idx,
+                    cfg.num_clients)
+                self.global_params = new_global
 
-        pos = [sel[i] for i in a_rel]
-        neg = [sel[i] for i in r_rel]
-        self.selector.update(pos, neg)
+                pos = [sel[i] for i in a_rel]
+                neg = [sel[i] for i in r_rel]
+                self.selector.update(pos, neg)
 
-        comm = comm_bytes(self.global_params, len(sel), len(pos),
-                          soft.shape[-1],
-                          control_variate=self.strategy.doubles_uplink)
-        rec = {"round": self.round_idx, "selected": sel, "positive": pos,
-               "negative": neg, "entropy": ent, "comm": comm}
-        self.history.append(rec)
-        self.round_idx += 1
+                comm = comm_bytes(self.global_params, len(sel), len(pos),
+                                  soft.shape[-1],
+                                  control_variate=self.strategy.doubles_uplink)
+                rec = {"round": self.round_idx, "selected": sel,
+                       "positive": pos, "negative": neg, "entropy": ent,
+                       "comm": comm}
+                self.history.append(rec)
+                self.round_idx += 1
         return rec
 
     # ------------------------------------------------------------------

@@ -1,0 +1,48 @@
+"""The profiler spans of a FedEntropy round, and its one readback helper.
+
+A round opens these ``jax.profiler.TraceAnnotation`` spans, nested under
+``fl.round``. They record only while a profiler session is active (for
+example inside ``jax.profiler.trace(log_dir)``), on the same clock as the
+device's programs in that trace. Otherwise they record nothing and cost
+0.4-0.6 us each on a TPU v5e host (timed with ``timeit``, JAX 0.9.0)::
+
+    fl.round      one Server.round(); keyword ``round`` = the round number
+      fl.select   the selector's draw (and the clustered assignment)
+      fl.stage    the cohort gathered off the data plane, the strategy's
+                  per-client inputs
+      fl.clients  the client program's dispatch
+      fl.fetch    one device-to-host readback (``fetch``), once each;
+                  a device selector's or judge's own readbacks are
+                  ``fl.fetch`` spans inside ``fl.select`` or ``fl.judge``
+      fl.judge    the judge's verdict and the admission mask
+      fl.aggregate  the aggregator
+      fl.feedback   strategy state, selector pools, uplink bytes, history
+
+Every device-to-host readback of a round goes through :func:`fetch`, so
+the number of ``fl.fetch`` spans in one ``fl.round`` is the number of
+times that round waited on the device.
+"""
+from __future__ import annotations
+
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+ROUND = "fl.round"
+SELECT = "fl.select"
+STAGE = "fl.stage"
+CLIENTS = "fl.clients"
+FETCH = "fl.fetch"
+JUDGE = "fl.judge"
+AGGREGATE = "fl.aggregate"
+FEEDBACK = "fl.feedback"
+NAMES = (ROUND, SELECT, STAGE, CLIENTS, FETCH, JUDGE, AGGREGATE, FEEDBACK)
+
+__all__ = ["AGGREGATE", "CLIENTS", "FEEDBACK", "FETCH", "JUDGE", "NAMES",
+           "ROUND", "SELECT", "STAGE", "fetch"]
+
+
+def fetch(x, dtype=None) -> np.ndarray:
+    """``np.asarray(x, dtype)`` under an ``fl.fetch`` span: the host waits
+    here for the device's answer."""
+    with TraceAnnotation(FETCH):
+        return np.asarray(x, dtype)
