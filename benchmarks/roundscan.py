@@ -51,6 +51,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -211,7 +212,8 @@ def _accum_f32_check(tree, sizes, mask) -> tuple[float, float, bool]:
 
 
 def time_aggregation(repeats: int = 200) -> dict:
-    """Jitted per-leaf tree_map mean vs the one-launch fused reduce."""
+    """The per-leaf tree_map mean vs the flat fused reduce, each one
+    compiled program."""
     m = 10
     cnn_params = cnn.init(jax.random.PRNGKey(0), image_hw=HW,
                           num_classes=4)
@@ -226,9 +228,8 @@ def time_aggregation(repeats: int = 200) -> dict:
     sizes = jnp.asarray(np.full(m, 10.0), jnp.float32)
     mask = jnp.asarray(([1.0, 0.0] * m)[:m], jnp.float32)
 
-    tree_fn = jax.jit(masked_mean_tree)
-    fused_fn = jax.jit(lambda t, s, k: fused_aggregate(t, s, k,
-                                                       backend="xla"))
+    tree_fn = masked_mean_tree               # both jitted already
+    fused_fn = partial(fused_aggregate, backend="xla")
     out = {}
     for name, tree in trees.items():
         leaves = jax.tree.leaves(tree)

@@ -7,14 +7,20 @@
                         axis of every leaf.
 ``fused_aggregate``   — the same reduction as one flat segment-reduce:
                         every leaf reshaped into a single (M, P) buffer and
-                        summed in one kernel launch (Pallas or xla) instead
-                        of a per-leaf tree_map — the launch-count win for
-                        LM-sized pytrees with hundreds of leaves.
+                        summed in one kernel (Pallas or xla).
 ``comm_bytes``        — accounting helper: uplink bytes actually transferred
                         for a round (positives upload models; every selected
                         device uploads its soft label first — stage 1).
+
+The three reductions are jitted: a call from the host is ONE compiled
+program (``jit_aggregate``, ``jit_masked_mean_tree``,
+``jit_fused_aggregate`` in a profile), not one eager launch per leaf and
+op; called inside a traced program (the scan engine's fold, an
+aggregator's own jit) they inline.
 """
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -23,13 +29,16 @@ import numpy as np
 _EPS = 1e-12
 
 
+@jax.jit
 def masked_mean_tree(stacked_tree, sizes: jax.Array, mask: jax.Array):
     """Weighted mean over leading axis M of every leaf, weights sizes*mask.
 
     Low-precision leaves (bf16/f16) accumulate in float32 — summing a
     large cohort in the leaf dtype loses mass (bf16 has 8 mantissa bits)
-    — and cast back on return. Float32 leaves run the identical ops as
-    before, so fixed-seed histories are unchanged bit-for-bit.
+    — and cast back on return. One compiled program for the whole tree;
+    XLA may fuse each leaf's multiply into its reduction, so against the
+    same ops run eagerly op by op a result can move by float32 summation
+    order (<= 1.5e-07 on the paper CNN stacked x10), not bit-for-bit.
     """
     w = (jnp.asarray(sizes, jnp.float32) * jnp.asarray(mask, jnp.float32))
     tot = jnp.clip(jnp.sum(w), _EPS, None)
@@ -43,6 +52,8 @@ def masked_mean_tree(stacked_tree, sizes: jax.Array, mask: jax.Array):
     return jax.tree.map(leaf, stacked_tree)
 
 
+@partial(jax.jit, static_argnames=("backend", "block_p",
+                                   "vmem_budget_bytes"))
 def fused_aggregate(stacked_tree, sizes: jax.Array, mask: jax.Array,
                     *, backend: str | None = None, block_p: int = 2048,
                     vmem_budget_bytes: int = 4 * 1024 * 1024):
@@ -81,6 +92,7 @@ def fused_aggregate(stacked_tree, sizes: jax.Array, mask: jax.Array,
     return jax.tree.unflatten(treedef, outs)
 
 
+@jax.jit
 def aggregate(stacked_params, sizes: jax.Array, mask: jax.Array):
     """Paper Alg. 2 line 21: w_g = sum_{i in A} L_i * W_i / sum_{i in A} L_i."""
     return masked_mean_tree(stacked_params, sizes, mask)

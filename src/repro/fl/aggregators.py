@@ -5,9 +5,9 @@
 ``FusedAverageAggregator``    — the same mean as ONE flat segment-reduce
                                 (``core.aggregation.fused_aggregate``):
                                 every leaf flattened into a single (M, P)
-                                buffer, reduced in one kernel launch
-                                (Pallas or xla) — float32-tolerance equal
-                                to ``weighted``, not bitwise, so it is an
+                                buffer, reduced in one kernel (Pallas or
+                                xla) — float32-tolerance equal to
+                                ``weighted``, not bitwise, so it is an
                                 opt-in (``aggregator="fused"``) rather
                                 than the golden-history default.
 ``ScaffoldAggregator``        — the same average, then the SCAFFOLD damped
@@ -19,14 +19,71 @@
                                 over the K-center cluster axis (one
                                 admitted-member average per center; empty
                                 clusters keep their center unchanged).
+
+Each aggregator call is ONE compiled program: its arithmetic is a jitted
+function of arrays only (the global params, the stacked client params,
+sizes, mask, and the chain or cluster ids it reads from the cohort's
+outputs), named so a profile shows it (``jit_aggregate``,
+``jit_fused_aggregate``, ``jit_scaffold_aggregate``,
+``jit_devconcat_aggregate``, ``jit_perclstr_aggregate``). The jit lives
+here rather than at the engines' call sites, so host-side wrappers around
+an aggregator keep working.
 """
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from ..core.aggregation import aggregate, fused_aggregate
 from .registry import register
+
+@partial(jax.jit, static_argnames=("lr_g",))
+def scaffold_aggregate(global_params, stacked_params, sizes, mask, lr_g):
+    avg = aggregate(stacked_params, sizes, mask)
+    return jax.tree.map(
+        lambda wg, ag: wg + lr_g * (ag.astype(wg.dtype) - wg),
+        global_params, avg)
+
+
+@jax.jit
+def devconcat_aggregate(global_params, stacked_params, sizes, mask,
+                        gid, pos):
+    m = jnp.asarray(mask, jnp.float32)
+    same = gid[None, :] == gid[:, None]
+    prefix = same & (pos[None, :] <= pos[:, None])
+    # ok[i]: every chain stage up to and including i was admitted
+    ok = jnp.all(jnp.where(prefix, m > 0, True), axis=1)
+    # the deepest unbroken stage represents its chain
+    deeper = same & (pos[None, :] > pos[:, None])
+    rep = (ok & ~jnp.any(deeper & ok[None, :], axis=1)).astype(jnp.float32)
+    # chain weight: total data size along the admitted prefix
+    w = jnp.sum(jnp.where(prefix, jnp.asarray(sizes, jnp.float32)[None, :],
+                          0.0), axis=1)
+    avg = aggregate(stacked_params, w, rep)
+    kept = jnp.sum(w * rep) > 0
+    return jax.tree.map(
+        lambda ag, wg: jnp.where(kept, ag, wg.astype(ag.dtype)),
+        avg, global_params)
+
+
+@partial(jax.jit, static_argnames=("base",))
+def perclstr_aggregate(global_params, out, sizes, mask, base):
+    cids = jnp.asarray(out["cluster"], jnp.int32)
+    sizes = jnp.asarray(sizes, jnp.float32)
+    mask = jnp.asarray(mask, jnp.float32)
+    k = jax.tree.leaves(global_params)[0].shape[0]
+    centers = []
+    for c in range(k):
+        member = (cids == c).astype(jnp.float32)
+        mk = mask * member
+        old = jax.tree.map(lambda s: s[c], global_params)
+        avg = base(old, out, sizes, mk)
+        kept = jnp.sum(sizes * mk) > 0
+        centers.append(jax.tree.map(
+            lambda a, o: jnp.where(kept, a.astype(o.dtype), o), avg, old))
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *centers)
 
 
 @register("aggregator", "weighted")
@@ -47,8 +104,9 @@ class FusedAverageAggregator:
 
     ``backend="pallas"`` tiles the flattened param axis through the VMEM
     kernel (``repro.kernels.fused_aggregate``); ``None``/"xla" uses the
-    fused-jnp reference. One launch instead of one-per-leaf — the win
-    grows with leaf count (LM pytrees; see benchmarks/roundscan.py).
+    fused-jnp reference. Like ``weighted`` it is one compiled program;
+    it differs only by reducing the flat ``(M, P)`` concatenate of every
+    leaf in one kernel, which costs an extra HBM pass over the cohort.
     """
 
     def __init__(self, backend: str | None = None):
@@ -75,11 +133,8 @@ class ScaffoldAggregator:
         return cls(local.scaffold_lr_g)
 
     def __call__(self, global_params, out, sizes, mask):
-        avg = aggregate(out["params"], sizes, mask)
-        eta = self.lr_g
-        return jax.tree.map(
-            lambda wg, ag: wg + eta * (ag.astype(wg.dtype) - wg),
-            global_params, avg)
+        return scaffold_aggregate(global_params, out["params"], sizes, mask,
+                                  lr_g=self.lr_g)
 
 
 @register("aggregator", "devconcat")
@@ -109,25 +164,8 @@ class DeviceConcatAggregator:
     def __call__(self, global_params, out, sizes, mask):
         if "group_id" not in out:        # not a chain cohort: plain FedAvg
             return aggregate(out["params"], sizes, mask)
-        gid, pos = out["group_id"], out["chain_pos"]
-        m = jnp.asarray(mask, jnp.float32)
-        same = gid[None, :] == gid[:, None]
-        prefix = same & (pos[None, :] <= pos[:, None])
-        # ok[i]: every chain stage up to and including i was admitted
-        ok = jnp.all(jnp.where(prefix, m > 0, True), axis=1)
-        # the deepest unbroken stage represents its chain
-        deeper = same & (pos[None, :] > pos[:, None])
-        rep = (ok & ~jnp.any(deeper & ok[None, :], axis=1)).astype(
-            jnp.float32)
-        # chain weight: total data size along the admitted prefix
-        w = jnp.sum(jnp.where(prefix,
-                              jnp.asarray(sizes, jnp.float32)[None, :],
-                              0.0), axis=1)
-        avg = aggregate(out["params"], w, rep)
-        kept = jnp.sum(w * rep) > 0
-        return jax.tree.map(
-            lambda ag, wg: jnp.where(kept, ag, wg.astype(ag.dtype)),
-            avg, global_params)
+        return devconcat_aggregate(global_params, out["params"], sizes,
+                                   mask, out["group_id"], out["chain_pos"])
 
 
 @register("aggregator", "perclstr")
@@ -160,18 +198,9 @@ class PerClusterAggregator:
     def __call__(self, global_params, out, sizes, mask):
         if "cluster" not in out:
             return self.base(global_params, out, sizes, mask)
-        cids = jnp.asarray(out["cluster"], jnp.int32)
-        sizes = jnp.asarray(sizes, jnp.float32)
-        mask = jnp.asarray(mask, jnp.float32)
-        k = jax.tree.leaves(global_params)[0].shape[0]
-        centers = []
-        for c in range(k):
-            member = (cids == c).astype(jnp.float32)
-            mk = mask * member
-            old = jax.tree.map(lambda s: s[c], global_params)
-            avg = self.base(old, out, sizes, mk)
-            kept = jnp.sum(sizes * mk) > 0
-            centers.append(jax.tree.map(
-                lambda a, o: jnp.where(kept, a.astype(o.dtype), o),
-                avg, old))
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *centers)
+        # only what an aggregator reads crosses the jit boundary, so the
+        # compile cache never keys on the cohort's other outputs
+        read = {k: out[k] for k in ("params", "group_id", "chain_pos",
+                                    "cluster") if k in out}
+        return perclstr_aggregate(global_params, read, sizes, mask,
+                                  base=self.base)

@@ -100,6 +100,51 @@ def test_build_runs_fedentropy_and_fedavg(tiny):
     assert not rec["negative"]
 
 
+class _HostReadingAggregator:
+    """A host-side wrapper that reads its inputs back with ``np.asarray``,
+    as the benchmark's recording wrapper does. It could not run under a
+    jit placed around the server's aggregator call."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.seen = []
+
+    def __call__(self, global_params, out, sizes, mask):
+        self.seen.append((
+            [np.asarray(x) for x in jax.tree.leaves(global_params)],
+            [np.asarray(x) for x in jax.tree.leaves(out["params"])],
+            np.asarray(sizes), np.asarray(mask)))
+        return self.inner(global_params, out, sizes, mask)
+
+
+def test_host_wrapper_around_aggregator_keeps_history(tiny):
+    """The aggregator's jit lives inside it, so wrapping
+    ``server.aggregator`` in host code still runs ``Server.round()`` and
+    gives the same history and model as the bare aggregator."""
+    data, params = tiny
+
+    def build():
+        return fl.build("fedentropy", cnn.apply, params, data,
+                        fl.ServerConfig(num_clients=8, participation=0.5,
+                                        seed=0),
+                        LocalSpec(epochs=1, batch_size=20))
+
+    bare, wrapped = build(), build()
+    wrapped.aggregator = _HostReadingAggregator(wrapped.aggregator)
+    for _ in range(3):
+        bare.round()
+        wrapped.round()
+    assert len(wrapped.aggregator.seen) == 3
+    for g, w in zip(wrapped.history, bare.history):
+        assert g["selected"] == w["selected"]
+        assert g["positive"] == w["positive"]
+        assert g["negative"] == w["negative"]
+        assert g["entropy"] == pytest.approx(w["entropy"], nan_ok=True)
+    for a, b in zip(jax.tree.leaves(wrapped.global_params),
+                    jax.tree.leaves(bare.global_params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ------------------------------------------------------- shim equivalence
 
 _VARIANTS = {

@@ -1,10 +1,18 @@
 """Device pools (Alg. 2 l.4-8/22) and weighted aggregation (l.21)."""
+import logging
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.aggregation import aggregate, comm_bytes
 from repro.core.pools import DevicePools
+from repro.fl.aggregators import (DeviceConcatAggregator,
+                                  FusedAverageAggregator,
+                                  PerClusterAggregator, ScaffoldAggregator,
+                                  WeightedAverageAggregator)
+from repro.models import cnn
 
 
 def test_pools_start_all_positive():
@@ -80,3 +88,87 @@ def test_comm_bytes_scaffold_doubles():
     a = comm_bytes(tmpl, 10, 10, 10, control_variate=False)
     b = comm_bytes(tmpl, 10, 10, 10, control_variate=True)
     assert b["model_bytes"] == 2 * a["model_bytes"]
+
+
+_M = 6
+_AGGREGATORS = {
+    "weighted": (WeightedAverageAggregator, None),
+    "fused": (lambda: FusedAverageAggregator(backend="xla"), None),
+    "scaffold": (lambda: ScaffoldAggregator(lr_g=0.5), None),
+    # three chains, the second broken at its second stage
+    "devconcat-chain": (DeviceConcatAggregator,
+                        {"group_id": [0, 0, 0, 1, 1, 2],
+                         "chain_pos": [0, 1, 2, 0, 1, 0]}),
+    "devconcat-plain": (DeviceConcatAggregator, None),
+    # K=3 centers, cluster 1 has no member
+    "perclstr": (PerClusterAggregator, {"cluster": [0, 0, 2, 2, 0, 2]}),
+}
+
+
+def _cohort(rng, dtype, k=None):
+    lead = () if k is None else (k,)
+
+    def tree(n):
+        return {"w": jnp.asarray(rng.normal(size=n + (3, 4)), dtype),
+                "b": jnp.asarray(rng.normal(size=n + (4,)), dtype)}
+
+    return tree(lead), tree((_M,))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(_AGGREGATORS))
+def test_compiled_aggregator_matches_eager_formula(rng, case, dtype):
+    """Each aggregator's one compiled program computes what its formula
+    gives evaluated op by op without jit, and keeps every leaf's dtype."""
+    make, extra = _AGGREGATORS[case]
+    agg = make()
+    k = 3 if case == "perclstr" else None
+    global_params, stacked = _cohort(rng, dtype, k)
+    out = {"params": stacked}
+    for key, val in (extra or {}).items():
+        out[key] = jnp.asarray(val, jnp.int32)
+    sizes = jnp.asarray([10, 20, 30, 40, 50, 60], jnp.float32)
+    mask = jnp.asarray([1, 1, 0, 1, 0, 1], jnp.float32)
+    got = agg(global_params, out, sizes, mask)
+    with jax.disable_jit():
+        want = agg(global_params, out, sizes, mask)
+    assert jax.tree.structure(got) == jax.tree.structure(global_params)
+    for g, w, ref in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                         jax.tree.leaves(global_params)):
+        assert g.dtype == ref.dtype and g.shape == ref.shape
+        g32, w32 = (np.asarray(x, np.float32) for x in (g, w))
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g32, w32, rtol=1e-6, atol=1e-7)
+        else:   # f32 accumulation both ways: at most one bf16 rounding
+            np.testing.assert_allclose(g32, w32, rtol=2.0 ** -7,
+                                       atol=2.0 ** -7)
+
+
+def test_weighted_aggregator_is_one_compiled_program(caplog):
+    """On the paper CNN (10 leaves) stacked x10, a first call compiles
+    exactly one program and a second compiles none: no per-leaf eager
+    launches."""
+    params = cnn.init(jax.random.PRNGKey(0))
+    assert len(jax.tree.leaves(params)) == 10
+    stacked = jax.tree.map(
+        lambda x: jnp.stack([x + 0.01 * i for i in range(10)]), params)
+    sizes = jnp.arange(1.0, 11.0, dtype=jnp.float32)
+    mask = jnp.asarray([1.0, 0.0] * 5, jnp.float32)
+    jax.block_until_ready((stacked, sizes, mask))
+    agg = WeightedAverageAggregator()
+    jax.clear_caches()
+
+    def compiles():
+        return [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("Compiling ")]
+
+    caplog.set_level(logging.WARNING)
+    with jax.log_compiles():
+        jax.block_until_ready(agg(params, {"params": stacked}, sizes, mask))
+        first = compiles()
+        caplog.clear()
+        jax.block_until_ready(agg(params, {"params": stacked}, sizes, mask))
+        second = compiles()
+    assert len(first) == 1 and first[0].startswith("Compiling jit(aggregate)")
+    assert second == []

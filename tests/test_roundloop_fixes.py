@@ -65,18 +65,25 @@ def test_masked_mean_bf16_accumulates_in_f32(seed):
 
 def test_masked_mean_f32_bitwise_unchanged():
     """Float32 leaves must run the identical ops as before the fix —
-    fixed-seed golden histories depend on it."""
+    fixed-seed golden histories depend on it. ``masked_mean_tree`` is one
+    compiled program, so the pre-fix formula is compiled too: the same
+    ops in one program give the same bits."""
     rng = np.random.default_rng(7)
     x = jnp.asarray(rng.normal(size=(8, 13, 4)), jnp.float32)
     sizes = jnp.asarray(rng.integers(20, 200, size=8), jnp.float32)
     mask = jnp.asarray([1, 0, 1, 1, 0, 1, 1, 0], jnp.float32)
     got = masked_mean_tree({"x": x}, sizes, mask)["x"]
-    # the pre-fix formula, verbatim: weights cast to the leaf dtype
-    w = sizes * mask
-    tot = jnp.clip(jnp.sum(w), 1e-12, None)
-    old = jnp.sum(x * w.reshape(-1, 1, 1).astype(x.dtype),
-                  axis=0) / tot.astype(x.dtype)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(old))
+
+    @jax.jit
+    def old(x, sizes, mask):
+        # the pre-fix formula, verbatim: weights cast to the leaf dtype
+        w = sizes * mask
+        tot = jnp.clip(jnp.sum(w), 1e-12, None)
+        return jnp.sum(x * w.reshape(-1, 1, 1).astype(x.dtype),
+                       axis=0) / tot.astype(x.dtype)
+
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(old(x, sizes, mask)))
 
 
 # ----------------------------------------------------- fused aggregation
