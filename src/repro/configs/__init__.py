@@ -9,6 +9,7 @@ from . import (
     internvl2_1b,
     kimi_k2_1t_a32b,
     mamba2_130m,
+    moonlight_16b_a3b,
     qwen3_0_6b,
     qwen3_moe_235b_a22b,
     whisper_large_v3,
@@ -20,11 +21,12 @@ ARCHS: dict[str, ModelConfig] = {
     for m in (
         mamba2_130m, whisper_large_v3, qwen3_0_6b, granite_8b,
         internvl2_1b, gemma_7b, zamba2_2_7b, qwen3_moe_235b_a22b,
-        chatglm3_6b, kimi_k2_1t_a32b, fedentropy_cnn,
+        chatglm3_6b, kimi_k2_1t_a32b, moonlight_16b_a3b, fedentropy_cnn,
     )
 }
 
-# the 10 assigned architectures (excludes the paper's own CNN)
+# the language-model architectures (every registered one but the paper's
+# own CNN)
 ASSIGNED = [n for n in ARCHS if n != "fedentropy-cnn"]
 
 

@@ -30,6 +30,19 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # DeepSeek-V3 style expert layer (models/moe.moe_held): experts_held > 0
+    # selects it; this layer holds experts_held of num_experts, routes
+    # over all of them by sigmoid scores and drops no assignment
+    experts_held: int = 0
+    moe_d_ff: int = 0                # routed expert width (0 -> d_ff)
+    num_shared_experts: int = 0      # one SwiGLU of num_shared * moe_d_ff
+    first_dense_layers: int = 0      # leading layers with a d_ff MLP
+    routed_scaling: float = 1.0      # held layer: top-k weights times this
+    # --- latent attention (MLA, q_lora_rank null) -------------------------
+    kv_lora_rank: int = 0            # > 0 selects MLA
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (Mamba2 / SSD) -------------------------------------------
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -96,6 +109,14 @@ class ModelConfig:
         if self.num_experts:
             kw["num_experts"] = 4
             kw["experts_per_token"] = 2
+            if self.experts_held:
+                kw["experts_held"] = 4
+        if self.moe_d_ff:
+            kw["moe_d_ff"] = min(self.moe_d_ff, 128)
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=64, qk_nope_head_dim=32,
+                      qk_rope_head_dim=16, v_head_dim=32,
+                      num_kv_heads=kw["num_heads"])
         if self.ssm_state:
             kw["ssm_state"] = min(self.ssm_state, 32)
             kw["ssm_headdim"] = 32

@@ -1,6 +1,9 @@
 """kimi-k2-1t-a32b — trillion-param MoE, 384 experts top-8 (paper-table)
-[arXiv:2501.kimi2]. Spec'd here with GQA kv=8 per the assignment (the real
-model uses MLA; the assignment pins GQA)."""
+[arXiv:2501.kimi2]. Spec'd here with GQA kv=8 per the assignment. The real
+model uses the DeepSeek-V3 block: MLA and a shared-expert, sigmoid-routed
+expert layer, which ``moonlight-16b-a3b`` runs (``kv_lora_rank``,
+``experts_held``, ``models/attention.mla_attention``,
+``models/moe.moe_held``); this config keeps the assignment's numbers."""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(

@@ -125,7 +125,11 @@ def make_train_step(
     ``judge_fn`` is the traced judge axis: (soft_labels, sizes) ->
     ``JudgmentResult``. Defaults to the maximum-entropy judgment; pass a
     ``repro.fl`` judge's ``.traced()`` to run any registered judge (or the
-    Pallas-backed sweep) inside the jitted step."""
+    Pallas-backed sweep) inside the jitted step.
+
+    The model's stats join the metrics: a model with held-expert layers
+    (``cfg.experts_held``) adds ``expert_rows`` (MoE layers, experts
+    held), the assignments each held expert computed."""
     cfg = model.cfg
     if judge_fn is None:
         judge_fn = judge
@@ -134,11 +138,11 @@ def make_train_step(
         tokens = batch["tokens"]
         m = fed.num_clients
         if fed.chunked_head:
-            h, aux = model.hidden(params, batch)
+            h, aux, stats = model.hidden(params, batch)
             client_loss, soft = chunked_head_stats(
                 cfg, params["tok"], h, tokens, m, fed.seq_chunk)
         else:
-            logits, aux = model.forward(params, batch)
+            logits, aux, stats = model.forward(params, batch)
             client_loss = _per_client_loss(cfg, logits, tokens, m)  # (M,)
             soft = None
         sizes = batch.get(
@@ -167,6 +171,7 @@ def make_train_step(
             "entropy_initial": ent0,
             "per_client_loss": client_loss,
         }
+        metrics.update(stats)
         return loss, metrics
 
     def train_step(params, opt_state, batch):
@@ -229,7 +234,7 @@ def make_microbatched_train_step(
 
         def body(carry, mb):
             soft_sum, loss_sum = carry
-            logits, _ = model.forward(params, mb)
+            logits, _, _ = model.forward(params, mb)
             soft = per_client_soft_labels(logits, m)
             loss = _per_client_loss(cfg, logits, mb["tokens"], m)
             return (soft_sum + soft, loss_sum + loss), None
@@ -256,7 +261,7 @@ def make_microbatched_train_step(
         w = mask * sizes
 
         def mb_loss(p, mb):
-            logits, aux = model.forward(p, mb)
+            logits, aux, _ = model.forward(p, mb)
             client_loss = _per_client_loss(cfg, logits, mb["tokens"], m)
             loss = jnp.sum(w * client_loss) / jnp.clip(jnp.sum(w), 1e-9)
             return loss + cfg.router_aux_weight * aux, client_loss
@@ -312,6 +317,8 @@ _PARAM_RULES: list[tuple[tuple[str, ...], tuple]] = [
     (("attn", "w_k", "w"), ("embed", "kv_heads")),
     (("attn", "w_v", "w"), ("embed", "kv_heads")),
     (("attn", "w_o", "w"), ("heads", "embed")),
+    (("attn", "w_kva", "w"), ("embed", None)),
+    (("attn", "w_kvb", "w"), (None, "heads")),
     (("xattn", "w_q", "w"), ("embed", "heads")),
     (("xattn", "w_k", "w"), ("embed", "kv_heads")),
     (("xattn", "w_v", "w"), ("embed", "kv_heads")),
@@ -319,6 +326,9 @@ _PARAM_RULES: list[tuple[tuple[str, ...], tuple]] = [
     (("mlp", "w_in", "w"), ("embed", "ffn")),
     (("mlp", "w_gate", "w"), ("embed", "ffn")),
     (("mlp", "w_out", "w"), ("ffn", "embed")),
+    (("shared", "w_in", "w"), ("embed", "ffn")),
+    (("shared", "w_gate", "w"), ("embed", "ffn")),
+    (("shared", "w_out", "w"), ("ffn", "embed")),
     (("moe", "router", "w"), ("embed", "experts")),
     (("moe", "w_in"), ("experts", "embed", "ffn")),
     (("moe", "w_gate"), ("experts", "embed", "ffn")),
@@ -367,6 +377,9 @@ def cache_logical_axes(cache_shape) -> Any:
         if last in ("k", "v"):        # (L, B, T, K, hd) or (B, T, K, hd)
             pad = leaf.ndim - 4
             return (None,) * pad + ("batch", "kv_time", "kv_heads", None)
+        if last in ("c_kv", "k_pe"):  # MLA latent (L, B, T, r)
+            pad = leaf.ndim - 3
+            return (None,) * pad + ("batch", "kv_time", None)
         if last == "state":           # (.., B, H, P, N)
             pad = leaf.ndim - 4
             return (None,) * pad + ("batch", "ssm_inner", None, None)

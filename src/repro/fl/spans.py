@@ -21,6 +21,14 @@ device's programs in that trace. Otherwise they record nothing and cost
 Every device-to-host readback of a round goes through :func:`fetch`, so
 the number of ``fl.fetch`` spans in one ``fl.round`` is the number of
 times that round waited on the device.
+
+The mesh engine's step opens one counter span, after its readback, where
+the model has held-expert layers (:func:`route_counter`)::
+
+    moe.route     keywords ``rows`` (assignments the held experts computed,
+                  summed over the MoE layers), ``max_rows`` and
+                  ``min_rows`` (the busiest and idlest held expert's, over
+                  every layer)
 """
 from __future__ import annotations
 
@@ -35,10 +43,13 @@ FETCH = "fl.fetch"
 JUDGE = "fl.judge"
 AGGREGATE = "fl.aggregate"
 FEEDBACK = "fl.feedback"
-NAMES = (ROUND, SELECT, STAGE, CLIENTS, FETCH, JUDGE, AGGREGATE, FEEDBACK)
+MOE_ROUTE = "moe.route"
+NAMES = (ROUND, SELECT, STAGE, CLIENTS, FETCH, JUDGE, AGGREGATE, FEEDBACK,
+         MOE_ROUTE)
 
-__all__ = ["AGGREGATE", "CLIENTS", "FEEDBACK", "FETCH", "JUDGE", "NAMES",
-           "ROUND", "SELECT", "STAGE", "fetch"]
+__all__ = ["AGGREGATE", "CLIENTS", "FEEDBACK", "FETCH", "JUDGE",
+           "MOE_ROUTE", "NAMES", "ROUND", "SELECT", "STAGE", "fetch",
+           "route_counter"]
 
 
 def fetch(x, dtype=None) -> np.ndarray:
@@ -46,3 +57,12 @@ def fetch(x, dtype=None) -> np.ndarray:
     here for the device's answer."""
     with TraceAnnotation(FETCH):
         return np.asarray(x, dtype)
+
+
+def route_counter(expert_rows) -> None:
+    """The ``moe.route`` counter span of one step, from its host copy of
+    the step's ``expert_rows`` (MoE layers, experts held)."""
+    rows = np.asarray(expert_rows)
+    with TraceAnnotation(MOE_ROUTE, rows=int(rows.sum()),
+                         max_rows=int(rows.max()), min_rows=int(rows.min())):
+        pass

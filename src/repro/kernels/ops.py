@@ -10,6 +10,7 @@ launcher's --kernels flag).
 """
 from __future__ import annotations
 
+import jax
 
 from . import ref
 
@@ -74,3 +75,44 @@ def masked_weighted_sum(flat, weights, *, backend=None, block_p=2048,
             flat, weights, block_p=block_p,
             vmem_budget_bytes=vmem_budget_bytes)
     return ref.masked_weighted_sum_reference(flat, weights)
+
+
+def gmm(lhs, rhs, group_sizes, *, backend=None):
+    """Grouped matrix product (``ref.gmm_reference``): ``lhs`` (R, K) rows
+    sorted by group, ``rhs`` (G, K, N), ``group_sizes`` (G,) int32 summing
+    to at most R; rows past the groups come out zero.
+
+    ``pallas`` is the megablox ``gmm`` kernel (its rows padded to its
+    128-row tile), the default on a TPU: on a v5e it ran the Moonlight
+    cell's step in 169 ms against 659 ms for ``jax.lax.ragged_dot``'s TPU
+    lowering. ``xla`` is ``jax.lax.ragged_dot``, the default elsewhere.
+    Neither writes the rows past the groups on a TPU, in the product or
+    in its gradient of ``lhs``: selects on both sides keep them out.
+
+    Where ``lhs`` is typed with a mesh (``launch.mesh.make_host_mesh``'s
+    axes are Explicit), neither has a sharding rule: the product runs in
+    ``shard_map`` with every operand whole on each device."""
+    import jax.numpy as jnp
+    from ._platform import target_platform
+    if backend is None:
+        backend = "pallas" if target_platform() == "tpu" else _DEFAULT
+    fn = _megablox_gmm if backend == "pallas" else jax.lax.ragged_dot
+    mesh = jax.typeof(lhs).sharding.mesh
+    if not mesh.empty:
+        from jax.sharding import PartitionSpec as P
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(), P(), P()),
+                           out_specs=P(), check_vma=False)
+    valid = (jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes))[:, None]
+    return jnp.where(valid, fn(jnp.where(valid, lhs, 0), rhs, group_sizes),
+                     0)
+
+
+def _megablox_gmm(lhs, rhs, group_sizes):
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.megablox import gmm as mb_gmm
+    from ._platform import resolve_interpret
+    r = lhs.shape[0]
+    out = mb_gmm(jnp.pad(lhs, ((0, -r % 128), (0, 0))), rhs, group_sizes,
+                 lhs.dtype, (128, 128, 128), None, None, False,
+                 resolve_interpret(None))
+    return out[:r]

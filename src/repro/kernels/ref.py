@@ -13,7 +13,7 @@ import jax.numpy as jnp
 def mha_reference(
     q: jax.Array,            # (B, S, H, D)
     k: jax.Array,            # (B, T, K, D)  K | H
-    v: jax.Array,            # (B, T, K, D)
+    v: jax.Array,            # (B, T, K, Dv)
     *,
     causal: bool = True,
     window: int = 0,          # 0 = unlimited
@@ -22,7 +22,8 @@ def mha_reference(
                                             # -1 = invalid (ring buffers)
     scale: float | None = None,
 ) -> jax.Array:
-    """Grouped-query attention with causal/sliding-window masking."""
+    """Grouped-query attention with causal/sliding-window masking; the
+    values' head size may differ from the queries' (MLA)."""
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
     g = h // kh
@@ -47,8 +48,8 @@ def mha_reference(
         mask = mask & (kv_pos[:, None, :] > q_pos[:, :, None] - window)
     logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, s, h, d).astype(q.dtype)
+    out = jnp.einsum("bkgst,btke->bskge", probs, v.astype(jnp.float32))
+    return out.reshape(b, s, h, v.shape[-1]).astype(q.dtype)
 
 
 def mha_blockwise(
@@ -261,3 +262,18 @@ def masked_weighted_sum_reference(
     aggregation kernel (one fused-jnp reduction over the client axis)."""
     w = jnp.asarray(weights, jnp.float32)
     return jnp.sum(flat.astype(jnp.float32) * w[:, None], axis=0)
+
+
+def gmm_reference(lhs: jax.Array, rhs: jax.Array,
+                  group_sizes: jax.Array) -> jax.Array:
+    """Grouped matrix product: rows ``[o_g, o_g + group_sizes[g])`` of
+    ``lhs`` (R, K), with ``o_g`` the sizes of the groups before g, times
+    ``rhs[g]`` (G, K, N); rows past the last group are zero. One masked
+    dense product per group."""
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(lhs.shape[0])[:, None]
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    for g in range(rhs.shape[0]):
+        mine = (row >= ends[g] - group_sizes[g]) & (row < ends[g])
+        out = out + jnp.where(mine, lhs @ rhs[g], 0.0)
+    return out.astype(lhs.dtype)

@@ -31,6 +31,13 @@ via ``--reduced``. Example:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --reduced \
       --engine pipelined --speculate --steps 10
 
+One chip's share of an expert-parallel MoE (the held-expert layer: 1 of 2
+shares of the reduced config's experts and vocabulary; at published widths
+``--arch moonlight-16b-a3b --expert-parallel 8 --num-layers 6``):
+
+  PYTHONPATH=src python -m repro.launch.train --arch moonlight-16b-a3b \
+      --reduced --expert-parallel 2 --steps 3 --clients 4 --seq-len 32
+
 LM quickstart (the scan engine at LM scale — eps-greedy pools folded on
 device, O(cohort x vocab) stacked bytes per round via remat):
 
@@ -52,6 +59,8 @@ import repro.fl as fl
 from ..configs import ARCHS
 from ..core.distributed import FedSpec, make_train_step
 from ..data.synthetic import make_token_dataset
+from ..fl.spans import route_counter
+from ..kernels._platform import compiling_for
 from ..optim import adamw, sgd
 from ..checkpoint import save
 from ..compile_cache import use_compile_cache
@@ -130,7 +139,7 @@ def lm_window_apply(model, cfg):
         if cfg.family == "encdec":
             batch["frames"] = jnp.zeros(
                 (b, cfg.encoder_seq, cfg.d_model), jnp.float32)
-        logits, _ = model.forward(params, batch)
+        logits, _, _ = model.forward(params, batch)
         logits = logits.astype(jnp.float32)
         return logits, logits[:, -1, :]
     return apply_fn
@@ -151,7 +160,7 @@ def lm_client_apply(model, cfg):
         if cfg.family == "encdec":
             batch["frames"] = jnp.zeros(
                 (b, cfg.encoder_seq, cfg.d_model), jnp.float32)
-        logits, _ = model.forward(params, batch)
+        logits, _, _ = model.forward(params, batch)
         last = logits[:, -1, :].astype(jnp.float32)
         return last, last
     return apply_fn
@@ -319,7 +328,9 @@ def mesh_step_memory(argv: list[str], mesh) -> dict:
     """``memory_analysis()`` of the mesh train step for ``argv`` (train's
     flags), compiled for ``mesh`` from abstract shapes: argument, output,
     alias, temporary and generated-code bytes, and ``total`` = argument
-    + output - alias + temporary, the device memory the step needs."""
+    + output - alias + temporary, the device memory the step needs.
+    Kernels take the choices of the mesh's platform (a described TPU
+    compiles the Pallas kernels it would run)."""
     args = parse_args(list(argv))
     _, model = build_lm(args)
     _, _, judge = _components(args, host_oracle=False)
@@ -334,7 +345,8 @@ def mesh_step_memory(argv: list[str], mesh) -> dict:
     tokens = jax.ShapeDtypeStruct(
         (args.clients * args.per_client_batch, args.seq_len + 1),
         jnp.int32, sharding=replicated)
-    with mesh, use_mesh(mesh):
+    with compiling_for(mesh.devices.flat[0].platform), mesh, \
+            use_mesh(mesh):
         compiled = jitted.lower(abstract(params), opt_state,
                                 {"tokens": tokens}).compile()
     ma = compiled.memory_analysis()
@@ -384,6 +396,8 @@ def run_mesh_engine(args, cfg, model, corpus, client_idx) -> list[dict]:
             selector.update(pos, neg)
             rec = {k: float(metrics[k]) for k in
                    ("loss", "num_positive", "entropy", "grad_norm")}
+            if "expert_rows" in metrics:
+                route_counter(metrics["expert_rows"])
             records.append(rec)
             print(f"step {it:4d} loss={rec['loss']:.4f} "
                   f"pos={int(rec['num_positive'])}/{m} "
@@ -405,6 +419,15 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--num-layers", type=int, default=0,
+                    help="run the first N layers only: one pipeline "
+                         "stage's depth (0 = all)")
+    ap.add_argument("--expert-parallel", type=int, default=1,
+                    help="run this chip's share of an N-chip expert-"
+                         "parallel group (rank 0): num_experts/N experts "
+                         "of each expert layer and vocab_size/N rows of "
+                         "the vocabulary; routing stays over every "
+                         "expert")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--clients", type=int, default=8,
                     help="client slots per round (M = |S_t|)")
@@ -520,10 +543,23 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
 
 def build_lm(args):
     """(config, model) for ``--arch``: f32 params and activations, no
-    remat; ``--reduced`` shrinks the widths."""
+    remat; ``--reduced`` shrinks the widths, ``--num-layers`` and
+    ``--expert-parallel`` cut the model to one chip's share."""
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = cfg.reduced()
+    if args.num_layers:
+        cfg = cfg.replace(num_layers=args.num_layers)
+    ep = args.expert_parallel
+    if ep > 1:
+        if not cfg.experts_held or cfg.num_experts % ep or \
+                cfg.vocab_size % ep:
+            raise SystemExit(
+                f"--expert-parallel {ep} needs a held-expert layer whose "
+                f"experts ({cfg.num_experts}) and vocabulary "
+                f"({cfg.vocab_size}) it divides")
+        cfg = cfg.replace(experts_held=cfg.num_experts // ep,
+                          vocab_size=cfg.vocab_size // ep)
     cfg = cfg.replace(remat="none", param_dtype="float32", dtype="float32")
     return cfg, build_model(cfg)
 

@@ -25,14 +25,14 @@ LONG_CONTEXT_WINDOW = 8192
 class Model:
     cfg: ModelConfig
     init: Callable[[jax.Array], Any]
-    forward: Callable[..., tuple[jax.Array, jax.Array]]
-    hidden: Callable[..., tuple[jax.Array, jax.Array]]
+    forward: Callable[..., tuple[jax.Array, jax.Array, dict]]
+    hidden: Callable[..., tuple[jax.Array, jax.Array, dict]]
     prefill: Callable[..., tuple[jax.Array, Any]]
     decode_step: Callable[..., tuple[jax.Array, Any]]
     init_cache: Callable[..., Any]
 
     def loss(self, params, batch, *, window: int | None = None):
-        logits, aux = self.forward(params, batch, window=window)
+        logits, aux, _ = self.forward(params, batch, window=window)
         tokens = batch["tokens"]
         loss = transformer.lm_loss(self.cfg, logits, tokens,
                                    batch.get("loss_weights"))

@@ -88,8 +88,9 @@ def _dec_embed(cfg, params, tokens, offset=0):
 
 
 def hidden(cfg: ModelConfig, params: dict, batch: dict,
-           *, window: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """Final-norm decoder hidden states (pre-logits), + aux=0."""
+           *, window: int | None = None
+           ) -> tuple[jax.Array, jax.Array, dict]:
+    """Final-norm decoder hidden states (pre-logits), aux=0, no stats."""
     window = cfg.sliding_window if window is None else window
     enc = encode(cfg, params, batch["frames"])
     x = _dec_embed(cfg, params, batch["tokens"])
@@ -108,14 +109,16 @@ def hidden(cfg: ModelConfig, params: dict, batch: dict,
     body = (jax.checkpoint(body) if cfg.remat == "full" else body)
     x, _ = jax.lax.scan(body, x, params["dec_layers"])
     h = norm_apply(cfg, params["final_norm"], x)
-    return h, jnp.zeros((), jnp.float32)
+    return h, jnp.zeros((), jnp.float32), {}
 
 
 def forward(cfg: ModelConfig, params: dict, batch: dict,
-            *, window: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """batch: {"tokens": (B,S), "frames": (B,T,D)} -> (logits, aux=0)."""
-    h, aux = hidden(cfg, params, batch, window=window)
-    return logits_apply(cfg, params["tok"], h), aux
+            *, window: int | None = None
+            ) -> tuple[jax.Array, jax.Array, dict]:
+    """batch: {"tokens": (B,S), "frames": (B,T,D)} -> (logits, aux=0,
+    no stats)."""
+    h, aux, stats = hidden(cfg, params, batch, window=window)
+    return logits_apply(cfg, params["tok"], h), aux, stats
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int,

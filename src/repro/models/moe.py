@@ -14,6 +14,12 @@ the §Perf pass replaces this with an explicit shard_map lax.all_to_all.
 
 Aux load-balance loss (Switch-style): E * sum_e f_e * p_e, where f_e is the
 fraction of tokens routed to e and p_e the mean router prob.
+
+``moe_held`` is the other expert layer, DeepSeek-V3's, selected by
+``cfg.experts_held`` > 0: it holds a contiguous share of the experts, routes
+over all of them, and computes only its share's part of the result for the
+tokens routed there, with no capacity (dropless), plus the shared experts.
+On one chip it runs without the expert-parallel exchange.
 """
 from __future__ import annotations
 
@@ -22,16 +28,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..kernels import ops
 from ..sharding.ctx import shard_act
-from .layers import dense_init, pdtype_of
+from .layers import dense_init, mlp_apply, mlp_init, pdtype_of
 
 
 def moe_init(key, cfg: ModelConfig) -> dict:
-    e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
+    """Router over every expert and the experts this layer holds (all of
+    them but for a held-expert layer, which adds the router's selection
+    bias and the shared experts)."""
+    e = cfg.experts_held or cfg.num_experts
+    d, f = cfg.d_model, cfg.moe_d_ff or cfg.d_ff
     ks = jax.random.split(key, 4)
     std_in, std_out = d ** -0.5, f ** -0.5
     p = {
-        "router": dense_init(ks[0], cfg, d, e, scale=0.02),
+        "router": dense_init(ks[0], cfg, d, cfg.num_experts, scale=0.02),
         "w_in": (jax.random.normal(ks[1], (e, d, f)) * std_in).astype(
             pdtype_of(cfg)),
         "w_gate": (jax.random.normal(ks[2], (e, d, f)) * std_in).astype(
@@ -39,6 +50,11 @@ def moe_init(key, cfg: ModelConfig) -> dict:
         "w_out": (jax.random.normal(ks[3], (e, f, d)) * std_out).astype(
             pdtype_of(cfg)),
     }
+    if cfg.experts_held:
+        p["router"]["bias"] = jnp.zeros((cfg.num_experts,), pdtype_of(cfg))
+    if cfg.num_shared_experts:
+        p["shared"] = mlp_init(jax.random.fold_in(key, 1), cfg, d,
+                               cfg.num_shared_experts * f)
     return p
 
 
@@ -211,3 +227,54 @@ def moe_block_pjit(cfg: ModelConfig, p: dict, x: jax.Array
     out = jnp.zeros((t, d), x.dtype).at[token_of].add(contrib)
     out = out.reshape(bsz, s, d)
     return shard_act(out, ("batch", "seq", "embed")), aux
+
+
+# ------------------------------------------------------------ held experts
+
+
+def route_topk(cfg: ModelConfig, router: dict, xt: jax.Array):
+    """(T, D) -> (weights (T, k) f32, experts (T, k)). Sigmoid scores are
+    computed in float32 at full precision; the bias moves the choice of
+    the top-k only; the chosen scores are renormalised and scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(xt.astype(jnp.float32),
+                                    router["w"].astype(jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, top_i = jax.lax.top_k(scores + router["bias"].astype(jnp.float32),
+                             cfg.experts_per_token)
+    w = jnp.take_along_axis(scores, top_i, axis=-1)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling, top_i
+
+
+def moe_held(cfg: ModelConfig, p: dict, x: jax.Array, rank: int = 0
+             ) -> tuple[jax.Array, jax.Array]:
+    """x: (B, S, D) -> (out (B, S, D), rows (experts_held,) int32: the
+    assignments each held expert computed). The layer holds experts
+    [rank * experts_held, (rank + 1) * experts_held).
+
+    The (token, choice) assignments are sorted by held expert, those of
+    other experts last; a token has at most min(k, held) held choices, so
+    the first T * min(k, held) sorted rows hold every held assignment and
+    none is dropped. The held experts' SwiGLU is three grouped products
+    over those rows (``ops.gmm``); each row's output, times its weight, is
+    added back to its token."""
+    bsz, s, d = x.shape
+    t, k, held = bsz * s, cfg.experts_per_token, cfg.experts_held
+    xt = x.reshape(t, d)
+    w, top_i = route_topk(cfg, p["router"], xt)
+    local = top_i - rank * held
+    local = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order = jnp.argsort(local, stable=True)[: t * min(k, held)]
+    rows = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+    token_of = order // k
+    xs = xt[token_of]
+    h = jax.nn.silu(ops.gmm(xs, p["w_gate"].astype(x.dtype), rows)) * \
+        ops.gmm(xs, p["w_in"].astype(x.dtype), rows)
+    y = ops.gmm(h, p["w_out"].astype(x.dtype), rows)
+    gate = jnp.where(jnp.arange(order.shape[0]) < jnp.sum(rows),
+                     w.reshape(-1)[order], 0.0).astype(x.dtype)
+    out = jnp.zeros((t, d), x.dtype).at[token_of].add(y * gate[:, None])
+    out = out.reshape(bsz, s, d)
+    if "shared" in p:
+        out = out + mlp_apply(cfg, p["shared"], x)
+    return shard_act(out, ("batch", "seq", "embed")), rows

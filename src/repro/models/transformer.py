@@ -2,7 +2,8 @@
 
 Families:
   dense  — [norm->attn, norm->mlp] x L
-  moe    — [norm->attn, norm->moe] x L
+  moe    — [norm->attn, norm->moe] x L, after ``first_dense_layers``
+           [norm->attn, norm->mlp] layers (their own stack, scanned first)
   ssm    — [norm->mamba2] x L
   hybrid — groups of (attn_every-1) ssm blocks + 1 SHARED attention block
            (zamba2): outer scan over groups, inner scan over the ssm stack;
@@ -11,6 +12,11 @@ Families:
 Layer params are stacked on a leading axis and consumed by ``lax.scan`` so
 HLO size / compile time are depth-independent (94-layer models compile on
 the CPU host). ``cfg.remat`` wraps the block body in ``jax.checkpoint``.
+Attention is latent (MLA) where ``cfg.kv_lora_rank`` > 0. A held-expert
+layer (``cfg.experts_held``) reports the assignments each held expert
+computed in the stats that ``backbone``/``hidden``/``forward`` return,
+as ``{"expert_rows": (MoE layers, experts_held) int32}``; other models'
+stats are empty.
 """
 from __future__ import annotations
 
@@ -36,16 +42,25 @@ def _block_kind(cfg: ModelConfig) -> str:
             "ssm": "ssm"}[cfg.family] if cfg.family != "hybrid" else "hybrid"
 
 
-def _attn_block_init(key, cfg):
+def _attn_block_init(key, cfg, moe=True):
     k1, k2 = jax.random.split(key)
     p = {"ln1": norm_init(cfg, cfg.d_model),
-         "attn": attn.attn_init(k1, cfg),
+         "attn": (attn.mla_init(k1, cfg) if cfg.kv_lora_rank
+                  else attn.attn_init(k1, cfg)),
          "ln2": norm_init(cfg, cfg.d_model)}
-    if cfg.family == "moe":
+    if cfg.family == "moe" and moe:
         p["moe"] = moe_mod.moe_init(k2, cfg)
     else:
         p["mlp"] = mlp_init(k2, cfg, cfg.d_model, cfg.d_ff)
     return p
+
+
+# the stacks of attention blocks, in the order a pass runs them
+STACKS = ("dense_layers", "layers")
+
+
+def _stacks(tree: dict) -> list[str]:
+    return [name for name in STACKS if name in tree]
 
 
 def _ssm_block_init(key, cfg):
@@ -63,8 +78,13 @@ def init(key: jax.Array, cfg: ModelConfig) -> dict:
                               "final_norm": norm_init(cfg, cfg.d_model)}
     kind = _block_kind(cfg)
     if kind in ("dense", "moe"):
+        n_dense = cfg.first_dense_layers
+        if n_dense:
+            params["dense_layers"] = _stacked(
+                lambda k: _attn_block_init(k, cfg, moe=False),
+                jax.random.fold_in(kl, 1), n_dense)
         params["layers"] = _stacked(
-            lambda k: _attn_block_init(k, cfg), kl, cfg.num_layers)
+            lambda k: _attn_block_init(k, cfg), kl, cfg.num_layers - n_dense)
     elif kind == "ssm":
         params["layers"] = _stacked(
             lambda k: _ssm_block_init(k, cfg), kl, cfg.num_layers)
@@ -91,15 +111,30 @@ def _hybrid_shape(cfg: ModelConfig) -> tuple[int, int]:
 # --------------------------------------------------------------- full pass
 
 
+def _self_attention(cfg, p, x, **kw):
+    if "w_kva" in p:
+        return attn.mla_attention(cfg, p, x, **kw)
+    return attn.self_attention(cfg, p, x, **kw)
+
+
+def _ffn(cfg, p, x):
+    """The block's MLP or expert layer on normed ``x``: (out, aux, rows);
+    ``rows`` is None but for a held-expert layer."""
+    if "mlp" in p:
+        return mlp_apply(cfg, p["mlp"], x), 0.0, None
+    if cfg.experts_held:
+        out, rows = moe_mod.moe_held(cfg, p["moe"], x)
+        return out, 0.0, rows
+    out, aux = moe_mod.moe_block(cfg, p["moe"], x)
+    return out, aux, None
+
+
 def _attn_block(cfg, p, x, *, window):
-    h, _ = attn.self_attention(cfg, p["attn"], norm_apply(cfg, p["ln1"], x),
-                               causal=True, window=window)
+    h, _ = _self_attention(cfg, p["attn"], norm_apply(cfg, p["ln1"], x),
+                           causal=True, window=window)
     x = x + h
-    if "moe" in p:
-        h, aux = moe_mod.moe_block(cfg, p["moe"], norm_apply(cfg, p["ln2"], x))
-    else:
-        h, aux = mlp_apply(cfg, p["mlp"], norm_apply(cfg, p["ln2"], x)), 0.0
-    return x + h, aux
+    h, aux, rows = _ffn(cfg, p, norm_apply(cfg, p["ln2"], x))
+    return x + h, aux, rows
 
 
 def _ssm_block(cfg, p, x):
@@ -116,19 +151,25 @@ def _maybe_remat(cfg, fn):
 
 
 def backbone(cfg: ModelConfig, params: dict, x: jax.Array,
-             *, window: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """(B, S, D) -> (hidden (B, S, D), aux_loss ()). Full-sequence pass."""
+             *, window: int | None = None
+             ) -> tuple[jax.Array, jax.Array, dict]:
+    """(B, S, D) -> (hidden (B, S, D), aux_loss (), stats). Full-sequence
+    pass; ``stats`` is empty but for held-expert layers."""
     window = cfg.sliding_window if window is None else window
     kind = _block_kind(cfg)
+    stats = {}
 
     if kind in ("dense", "moe"):
         def body(carry, lp):
             h, aux = carry
-            h, a = _attn_block(cfg, lp, h, window=window)
-            return (h, aux + a), None
-        (x, aux), _ = jax.lax.scan(_maybe_remat(cfg, body),
-                                   (x, jnp.zeros((), jnp.float32)),
-                                   params["layers"])
+            h, a, rows = _attn_block(cfg, lp, h, window=window)
+            return (h, aux + a), rows
+        aux = jnp.zeros((), jnp.float32)
+        for name in _stacks(params):
+            (x, aux), rows = jax.lax.scan(_maybe_remat(cfg, body), (x, aux),
+                                          params[name])
+            if rows is not None:
+                stats["expert_rows"] = rows
     elif kind == "ssm":
         def body(carry, lp):
             return _ssm_block(cfg, lp, carry), None
@@ -143,12 +184,12 @@ def backbone(cfg: ModelConfig, params: dict, x: jax.Array,
             def inner(c, lp):
                 return _ssm_block(cfg, lp, c), None
             h, _ = jax.lax.scan(inner, h, gp)
-            h, _ = _attn_block(cfg, shared, h, window=window)
+            h, _, _ = _attn_block(cfg, shared, h, window=window)
             return h, None
         x, _ = jax.lax.scan(_maybe_remat(cfg, group), x,
                             params["ssm_layers"])
         aux = jnp.zeros((), jnp.float32)
-    return norm_apply(cfg, params["final_norm"], x), aux
+    return norm_apply(cfg, params["final_norm"], x), aux, stats
 
 
 def embed_tokens(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
@@ -161,20 +202,24 @@ def embed_tokens(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
 
 
 def hidden(cfg: ModelConfig, params: dict, batch: dict,
-           *, window: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """Final-norm hidden states over text positions (pre-logits), + aux."""
+           *, window: int | None = None
+           ) -> tuple[jax.Array, jax.Array, dict]:
+    """Final-norm hidden states over text positions (pre-logits), aux,
+    stats."""
     x = embed_tokens(cfg, params, batch)
-    h, aux = backbone(cfg, params, x, window=window)
+    h, aux, stats = backbone(cfg, params, x, window=window)
     if cfg.family == "vlm":                      # logits only on text slots
         h = h[:, cfg.num_patches:]
-    return h, aux
+    return h, aux, stats
 
 
 def forward(cfg: ModelConfig, params: dict, batch: dict,
-            *, window: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """Training/eval forward. Returns (logits over text positions, aux)."""
-    h, aux = hidden(cfg, params, batch, window=window)
-    return logits_apply(cfg, params["tok"], h), aux
+            *, window: int | None = None
+            ) -> tuple[jax.Array, jax.Array, dict]:
+    """Training/eval forward. Returns (logits over text positions, aux,
+    stats)."""
+    h, aux, stats = hidden(cfg, params, batch, window=window)
+    return logits_apply(cfg, params["tok"], h), aux, stats
 
 
 def lm_loss(cfg: ModelConfig, logits: jax.Array, tokens: jax.Array,
@@ -199,9 +244,15 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
     cache: dict[str, Any] = {"index": jnp.zeros((), jnp.int32)}
     if kind in ("dense", "moe"):
         cache["pos"] = jnp.full((cache_len,), -1, jnp.int32)
-        cache["layers"] = jax.vmap(
-            lambda _: attn.cache_init(cfg, batch, cache_len, dtype)
-        )(jnp.arange(cfg.num_layers))
+        layer_cache = attn.mla_cache_init if cfg.kv_lora_rank else \
+            attn.cache_init
+        depth = {"dense_layers": cfg.first_dense_layers,
+                 "layers": cfg.num_layers - cfg.first_dense_layers}
+        for name in STACKS:
+            if depth[name]:
+                cache[name] = jax.vmap(
+                    lambda _: layer_cache(cfg, batch, cache_len, dtype)
+                )(jnp.arange(depth[name]))
     elif kind == "ssm":
         cache["layers"] = jax.vmap(
             lambda _: ssm_mod.ssm_cache_init(cfg, batch, dtype)
@@ -230,26 +281,23 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
 
     if kind in ("dense", "moe"):
         pos_tags = cache["pos"]
+        decode_attn = attn.mla_decode if cfg.kv_lora_rank else \
+            attn.decode_self_attention
 
         def body(carry, scanned):
             h = carry
             lp, lc = scanned
             hn = norm_apply(cfg, lp["ln1"], h)
-            a, updated = attn.decode_self_attention(
-                cfg, lp["attn"], hn, lc, index, pos_tags, window=window)
+            a, updated = decode_attn(cfg, lp["attn"], hn, lc, index,
+                                     pos_tags, window=window)
             h = h + a
-            if "moe" in lp:
-                m, _ = moe_mod.moe_block(cfg, lp["moe"],
-                                         norm_apply(cfg, lp["ln2"], h))
-            else:
-                m = mlp_apply(cfg, lp["mlp"], norm_apply(cfg, lp["ln2"], h))
-            h = h + m
-            return h, {"k": updated["k"], "v": updated["v"],
-                       "pos": updated["pos"]}
+            m, _, _ = _ffn(cfg, lp, norm_apply(cfg, lp["ln2"], h))
+            return h + m, updated
 
-        x, upd = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-        new_cache["layers"] = {"k": upd["k"], "v": upd["v"]}
-        new_cache["pos"] = upd["pos"][0]    # identical across layers
+        for name in _stacks(params):
+            x, upd = jax.lax.scan(body, x, (params[name], cache[name]))
+            new_cache["pos"] = upd.pop("pos")[0]  # identical across layers
+            new_cache[name] = upd
     elif kind == "ssm":
         def body(carry, scanned):
             h = carry
@@ -295,12 +343,12 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
 
 
 def _place(kv_s: jax.Array, cache_len: int) -> jax.Array:
-    """Embed prefill KV (L,B,S,K,hd) at the head of a cache_len buffer."""
-    l, b, s, k, hd = kv_s.shape
-    if cache_len == s:
+    """Embed prefill KV (L, B, S, ...) at the head of a cache_len buffer."""
+    pad = cache_len - kv_s.shape[2]
+    if not pad:
         return kv_s
-    out = jnp.zeros((l, b, cache_len, k, hd), kv_s.dtype)
-    return jax.lax.dynamic_update_slice(out, kv_s, (0, 0, 0, 0, 0))
+    return jnp.pad(kv_s, [(0, 0), (0, 0), (0, pad)] +
+                   [(0, 0)] * (kv_s.ndim - 3))
 
 
 def _pos_tags(s: int, cache_len: int) -> jax.Array:
@@ -328,19 +376,16 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict,
     if kind in ("dense", "moe"):
         def body(carry, lp):
             h = carry
-            a, kv = attn.self_attention(cfg, lp["attn"],
-                                        norm_apply(cfg, lp["ln1"], h),
-                                        causal=True, window=window,
-                                        positions=positions)
+            a, kv = _self_attention(cfg, lp["attn"],
+                                    norm_apply(cfg, lp["ln1"], h),
+                                    causal=True, window=window,
+                                    positions=positions)
             h = h + a
-            if "moe" in lp:
-                m, _ = moe_mod.moe_block(cfg, lp["moe"],
-                                         norm_apply(cfg, lp["ln2"], h))
-            else:
-                m = mlp_apply(cfg, lp["mlp"], norm_apply(cfg, lp["ln2"], h))
+            m, _, _ = _ffn(cfg, lp, norm_apply(cfg, lp["ln2"], h))
             return h + m, kv
-        x, kvs = jax.lax.scan(body, x, params["layers"])
-        cache["layers"] = jax.tree.map(lambda t: _place(t, cache_len), kvs)
+        for name in _stacks(params):
+            x, kvs = jax.lax.scan(body, x, params[name])
+            cache[name] = jax.tree.map(lambda t: _place(t, cache_len), kvs)
         cache["pos"] = _pos_tags(s, cache_len)
     elif kind == "ssm":
         def body(carry, lp):

@@ -146,13 +146,13 @@ def test_chunked_head_stats_match_dense(rng):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 20)), jnp.int32)
-    h, _ = model.hidden(params, {"tokens": toks})
+    h, _, _ = model.hidden(params, {"tokens": toks})
     pcl, soft = chunked_head_stats(cfg, params["tok"], h, toks, 2,
                                    seq_chunk=8)
     # dense reference
     from repro.core.distributed import (
         _per_client_loss, per_client_soft_labels)
-    logits, _ = model.forward(params, {"tokens": toks})
+    logits, _, _ = model.forward(params, {"tokens": toks})
     ref_pcl = _per_client_loss(cfg, logits, toks, 2)
     ref_soft = per_client_soft_labels(logits, 2)
     np.testing.assert_allclose(np.asarray(pcl), np.asarray(ref_pcl),

@@ -94,7 +94,7 @@ def test_distributed_step_equals_weighted_grad(rng):
 
     # explicit per-client grads of the same loss
     def client_loss(p, client):
-        lg, aux = model.forward(
+        lg, aux, _ = model.forward(
             p, {"tokens": tokens[client * per:(client + 1) * per]})
         from repro.models.transformer import lm_loss
         return lm_loss(cfg, lg, tokens[client * per:(client + 1) * per]) \

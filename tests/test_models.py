@@ -35,7 +35,7 @@ def test_smoke_forward_and_train_step(arch, rng):
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, rng, b=4, s=16)
 
-    logits, aux = model.forward(params, batch)
+    logits, aux, _ = model.forward(params, batch)
     assert logits.shape == (4, 16, cfg.padded_vocab)
     assert not bool(jnp.isnan(logits).any())
 
@@ -69,7 +69,7 @@ def test_decode_matches_forward(arch, rng):
     extra = {k: v for k, v in batch.items() if k != "tokens"}
     cache_extra = cfg.num_patches if cfg.family == "vlm" else 0
 
-    full_logits, _ = model.forward(params, batch)
+    full_logits, _, _ = model.forward(params, batch)
     logits, cache = model.prefill(
         params, {"tokens": toks[:, :s0], **extra},
         cache_len=s0 + sd + cache_extra)
@@ -91,7 +91,7 @@ def test_sliding_window_ring_buffer_decode(rng):
                        jnp.int32)
 
     # reference: full cache, windowed attention
-    full_logits, _ = model.forward(params, {"tokens": toks}, window=w)
+    full_logits, _, _ = model.forward(params, {"tokens": toks}, window=w)
 
     # ring cache of exactly window size
     cache = model.init_cache(b, w)
@@ -109,7 +109,7 @@ def test_moe_router_load_balance_aux(rng):
     cfg = ARCHS["qwen3-moe-235b-a22b"].reduced()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    _, aux = model.forward(params, _batch(cfg, rng))
+    _, aux, _ = model.forward(params, _batch(cfg, rng))
     # Switch aux loss >= 1 (equality iff perfectly balanced)
     assert float(aux) >= 0.99
 
@@ -119,9 +119,9 @@ def test_vlm_patch_conditioning_changes_logits(rng):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, rng)
-    l1, _ = model.forward(params, batch)
+    l1, _, _ = model.forward(params, batch)
     batch2 = dict(batch, patches=batch["patches"] + 1.0)
-    l2, _ = model.forward(params, batch2)
+    l2, _, _ = model.forward(params, batch2)
     assert float(jnp.abs(l1 - l2).max()) > 1e-4
 
 
@@ -130,8 +130,8 @@ def test_encdec_frames_conditioning(rng):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, rng)
-    l1, _ = model.forward(params, batch)
-    l2, _ = model.forward(params, dict(batch,
+    l1, _, _ = model.forward(params, batch)
+    l2, _, _ = model.forward(params, dict(batch,
                                        frames=batch["frames"] * 2.0))
     assert float(jnp.abs(l1 - l2).max()) > 1e-4
 

@@ -121,3 +121,25 @@ def test_qwen3_mesh_step_memory(one_chip, seq_len, fits):
         with pytest.raises(jax.errors.JaxRuntimeError,
                            match="RESOURCE_EXHAUSTED"):
             mesh_step_memory(argv, mesh)
+
+
+def test_moonlight_mesh_step_memory(one_chip, monkeypatch):
+    """The Moonlight cell's step (one chip's share at published widths:
+    the dense layer and 5 expert layers, 8 of 64 experts, a 20,480-row
+    vocabulary; 4 silos x 2 sequences of 257 tokens, f32 params and SGD
+    momentum) compiles for one v5e, with the megablox grouped products it
+    runs there, within what XLA gives one program."""
+    from repro.kernels import ops
+    calls = []
+    megablox = ops._megablox_gmm
+    monkeypatch.setattr(ops, "_megablox_gmm",
+                        lambda *a: calls.append(1) or megablox(*a))
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    mem = mesh_step_memory(
+        ["--arch", "moonlight-16b-a3b", "--clients", "4",
+         "--per-client-batch", "2", "--seq-len", "256",
+         "--expert-parallel", "8", "--num-layers", "6"], mesh)
+    assert len(calls) == 3          # gate, up, down of the scanned layer
+    assert mem["argument"] > 4 * 668_860_416 * 2     # params + momentum
+    assert mem["total"] <= V5E_PROGRAM_HBM
