@@ -180,15 +180,26 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                np.asarray(want), rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_grouped_product_matches_its_oracle(backend):
-    """``ops.gmm`` (ragged_dot; megablox in the Pallas interpreter)
-    against ``ref.gmm_reference``, values and gradients, with rows past
-    the groups."""
+@pytest.mark.parametrize("backend,rows,k,n,sizes", [
+    pytest.param("xla", 200, 128, 256, [50, 0, 90], id="xla"),
+    pytest.param("pallas", 200, 128, 256, [50, 0, 90], id="pallas"),
+    # whole dimensions that are not 128-multiples: tiles (128, 1024, 704)
+    pytest.param("pallas", 384, 1024, 704, [130, 0, 160],
+                 id="pallas-1024x704"),
+    # the Moonlight cell's widths: k in 512-row tiles (gate, up and their
+    # weights' gradient), n in 512-column tiles (the rows' gradient)
+    pytest.param("pallas", 300, 2048, 1408, [120, 0, 140],
+                 id="pallas-2048x1408"),
+])
+def test_grouped_product_matches_its_oracle(backend, rows, k, n, sizes):
+    """``ops.gmm`` (ragged_dot; megablox in the Pallas interpreter, in
+    the tiles ``ops.gmm_tiles`` gives its forward and both gradients)
+    against ``ref.gmm_reference``, values and gradients, with an empty
+    group and rows past the groups."""
     rng = np.random.default_rng(6)
-    lhs = jnp.asarray(rng.normal(size=(200, 128)), jnp.float32)
-    rhs = jnp.asarray(rng.normal(size=(3, 128, 256)), jnp.float32)
-    sizes = jnp.asarray([50, 0, 90], jnp.int32)
+    lhs = jnp.asarray(rng.normal(size=(rows, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(3, k, n)), jnp.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
 
     def loss(fn, a, b):
         return jnp.sum(jnp.sin(fn(a, b, sizes)))
@@ -199,10 +210,43 @@ def test_grouped_product_matches_its_oracle(backend):
             lambda *z: ops.gmm(*z, backend=backend), a, b), (0, 1))(lhs, rhs)
         g_want = jax.grad(lambda a, b: loss(kref.gmm_reference, a, b),
                           (0, 1))(lhs, rhs)
-    assert float(jnp.max(jnp.abs(got[140:]))) == 0.0
-    # f32 dot products of 128 terms: rounding only
+    assert float(jnp.max(jnp.abs(got[int(jnp.sum(sizes)):]))) == 0.0
+    # f32 dot products at highest precision: rounding only
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-3)
+
+
+# The Moonlight cell's expert layer: 2,056 tokens x 6 choices in a buffer
+# of 12,336 rows, padded to 128-row tiles; gate and up (2048 -> 1408),
+# down (1408 -> 2048). Each runs forward, as the rows' gradient (k and n
+# swapped) and as the weights' gradient (``tgmm``, contracting the rows).
+CELL_ROWS = 12416
+VMEM_BUDGET = 12 * 2**20
+
+
+def _whole_or_128_divisor(tile, dim):
+    return tile == dim or (tile % 128 == 0 and dim % tile == 0)
+
+
+@pytest.mark.parametrize("product", ["gmm", "rows_grad", "tgmm"])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_grouped_product_tiles_fit_the_cell(product, k, n):
+    """The tiles ``ops.gmm_tiles`` gives each of the cell's six product
+    shapes are legal for megablox, fit the VMEM budget as that kernel
+    holds them, and run a tenth or fewer of the (128, 128, 128) grid."""
+    m = CELL_ROWS
+    if product == "rows_grad":
+        k, n = n, k
+    tm, tk, tn = ops.gmm_tiles(m, k, n, 4)
+    assert m % tm == 0 and tm % 128 == 0
+    assert _whole_or_128_divisor(tk, k) and _whole_or_128_divisor(tn, n)
+    # double-buffered lhs, rhs and out tiles, and the f32 accumulator:
+    # (tm, tn) in ``gmm``, (tk, tn) in ``tgmm``
+    acc = tk * tn if product == "tgmm" else tm * tn
+    assert 2 * 4 * (tm * tk + tk * tn + tm * tn) + 4 * acc <= VMEM_BUDGET
+    steps = (m // tm) * (k // tk) * (n // tn)
+    assert 10 * steps <= (m // 128) * (k // 128) * (n // 128)
+    assert ops._gmm_tiling(4) is ops._gmm_tiling(4)   # one static arg
