@@ -74,7 +74,7 @@ def time_engines(setup, local: LocalSpec, num_clients: int,
         jax.block_until_ready(server.global_params)
         pending = getattr(server, "_pending", None)
         if pending is not None:
-            jax.block_until_ready(pending[1])
+            jax.block_until_ready(pending[-1])   # (sel, cids, out)
 
     servers = {}
     for name in ENGINES:

@@ -21,7 +21,7 @@ registry without forking the engines:
   what lets the pipelined engine speculate through it.
 
 Judgment and aggregation run *within* each cluster: the server masks the
-round's verdict per cluster (``Server._judge_clusters``) and the
+round's verdict per cluster (``Server._verdict``) and the
 ``perclstr`` aggregator (:mod:`repro.fl.aggregators`) averages each
 center over its admitted members only, keeping empty clusters' centers
 unchanged. Compositions: ``ifca``, ``ifca+maxent`` (per-cluster

@@ -27,8 +27,9 @@ against both a live sequential ``Server`` and the recorded goldens): with
 ``buffer_size = |cohort|``, the zero-latency clock, and damping off, every
 dispatch arrives as one simultaneous batch, admission over the empty
 buffer *is* the sequential round judgment (float64 oracle), and the flush
-replays ``Server.round``'s exact aggregate/state/selector sequence — so
-histories and parameters equal the sequential engine's exactly.
+runs ``Server.round``'s own merge stage (aggregate, state, selector,
+record) — so histories and parameters equal the sequential engine's
+exactly.
 
 Determinism: the only random streams are the selector's (advanced exactly
 once per dispatched cohort) and the latency model's own
@@ -43,8 +44,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from ...core.aggregation import comm_bytes
+from .. import spans
 from ..judges import admit_candidates
 from ..registry import register
 from .engine import PipelinedServer, RuntimeConfig
@@ -206,10 +208,10 @@ class AsyncBufferedServer(PipelinedServer):
         with every selected client in the comm model; only admitted clients
         later ship weights).
         """
-        sel = self.selector.select(self._cohort_size())
+        with TraceAnnotation(spans.SELECT):
+            sel = self.selector.select(self._cohort_size())
         out = self._run_cohort(sel, self.selector)
-        soft = np.asarray(out["soft_label"], np.float64)
-        sizes = np.asarray(out["size"], np.float64)
+        soft, sizes = self._readback(out)
         for row, client in enumerate(sel):
             entry = {"client": int(client), "row": row, "out": out,
                      "soft": soft[row], "size": float(sizes[row]),
@@ -246,12 +248,13 @@ class AsyncBufferedServer(PipelinedServer):
             buf_soft = np.zeros((0, cand_soft.shape[1]), np.float64)
             buf_sizes = np.zeros((0,), np.float64)
         admit = getattr(self.judge, "admit", None)
-        if admit is None:
-            a_rel, r_rel, ent = admit_candidates(
-                self.judge, buf_soft, buf_sizes, cand_soft, cand_sizes)
-        else:
-            a_rel, r_rel, ent = admit(buf_soft, buf_sizes,
-                                      cand_soft, cand_sizes)
+        with TraceAnnotation(spans.JUDGE):
+            if admit is None:
+                a_rel, r_rel, ent = admit_candidates(
+                    self.judge, buf_soft, buf_sizes, cand_soft, cand_sizes)
+            else:
+                a_rel, r_rel, ent = admit(buf_soft, buf_sizes,
+                                          cand_soft, cand_sizes)
         admitted = set(a_rel)
         for i, entry in enumerate(batch):
             entry["admitted"] = i in admitted
@@ -263,14 +266,11 @@ class AsyncBufferedServer(PipelinedServer):
 
     # -------------------------------------------------------------- flush
     def _flush(self) -> dict:
-        """Aggregate the screened window; replays ``Server.round``'s exact
-        aggregate → state → selector sequence over the arrival-ordered
-        rows, so the K=|cohort| zero-latency case is bit-for-bit the
-        sequential round."""
-        cfg = self.config
+        """Aggregate the screened window through ``Server``'s merge stage
+        over the arrival-ordered rows, so the K=|cohort| zero-latency case
+        is bit-for-bit the sequential round."""
         log = self._flush_log
         sel = [e["client"] for e in log]
-        idx = np.asarray(sel)
         rows = [jax.tree.map(lambda x, r=e["row"]: x[r], e["out"])
                 for e in log]
         out = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
@@ -285,15 +285,16 @@ class AsyncBufferedServer(PipelinedServer):
         weights = sizes if alpha == 0.0 else \
             sizes * staleness_weights(tau, alpha)
 
-        new_global = self.aggregator(
-            self.global_params, out,
-            jnp.asarray(weights, jnp.float32), jnp.asarray(mask))
-        self.state = self.strategy.update_state(
-            self.state, self.global_params, out, idx, cfg.num_clients)
-        self.global_params = new_global
-
-        pos, neg = self._pos_log, self._neg_log
-        self.selector.update(pos, neg)
+        verdict = (mask, self._pos_log, self._neg_log, self._last_ent, None)
+        rec = self._merge(
+            self.global_params, out, weights, verdict, sel,
+            # async extras: the sequential record plus stream telemetry
+            flush_time=float(self._vtime),
+            staleness=[int(t) for t in tau],
+            buffer_occupancy=len(self._buffer),
+            inflight=len(self._events),
+            seq=[e["seq"] for e in log],
+            admitted_seq=[e["seq"] for e in log if e["admitted"]])
         # staleness feedback plumbing: selectors exposing
         # ``observe_staleness`` see each screened arrival's τ (flushes
         # elapsed since its dispatch version) alongside the verdict, in
@@ -305,21 +306,7 @@ class AsyncBufferedServer(PipelinedServer):
             observe([{"client": e["client"], "staleness": int(t),
                       "admitted": bool(e["admitted"])}
                      for e, t in zip(log, tau)])
-
-        comm = comm_bytes(self.global_params, len(sel), len(pos),
-                          log[0]["soft"].shape[-1],
-                          control_variate=self.strategy.doubles_uplink)
-        rec = {"round": self.round_idx, "selected": sel, "positive": pos,
-               "negative": neg, "entropy": self._last_ent, "comm": comm,
-               # async extras: the sequential record plus stream telemetry
-               "flush_time": float(self._vtime),
-               "staleness": [int(t) for t in tau],
-               "buffer_occupancy": len(self._buffer),
-               "inflight": len(self._events),
-               "seq": [e["seq"] for e in log],
-               "admitted_seq": [e["seq"] for e in log if e["admitted"]]}
-        self.history.append(rec)
-        self.round_idx += 1
+        self._commit(rec)
         self._buffer, self._flush_log = [], []
         self._pos_log, self._neg_log = [], []
         self._last_ent = float("nan")
@@ -332,7 +319,8 @@ class AsyncBufferedServer(PipelinedServer):
         whole, so a flush can exceed K by the tie overshoot (the zero
         clock flushes exact cohorts)."""
         k = self.buffer_size
-        while len(self._flush_log) < k:
-            self._ensure_inflight()
-            self._screen(self._pop_batch())
-        return self._flush()
+        with TraceAnnotation(spans.ROUND, round=self.round_idx):
+            while len(self._flush_log) < k:
+                self._ensure_inflight()
+                self._screen(self._pop_batch())
+            return self._flush()

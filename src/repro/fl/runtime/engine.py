@@ -56,9 +56,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from ...core.aggregation import comm_bytes
 from ...core.judgment import judge as traced_judge
+from .. import spans
 from ..judges import MaxEntropyJudge
 from ..registry import register
 from ..server import Server
@@ -107,7 +108,7 @@ class PipelinedServer(Server):
                 f"{type(self).__name__} takes runtime=RuntimeConfig, got "
                 f"{type(self.runtime).__name__}")
         self._mesh = mesh
-        self._pending = None           # (sel, out) dispatched for round t+1
+        self._pending = None           # (sel, cids, out) for round t+1
         self._redispatch_next = False  # previous speculation missed
 
     # ---------------------------------------------------------- sharding
@@ -176,15 +177,6 @@ class PipelinedServer(Server):
         return self._compile_cache().get(
             ("spec-judge", self.judge, self.runtime.spec_backend), make)
 
-    def _dispatch(self, sel, selector=None, global_params=None):
-        """Launch a cohort's client compute (async). ``selector`` is whoever
-        produced ``sel`` — under speculation a throwaway copy whose group
-        assignment must ride with this dispatch (the group is the dispatch
-        unit), never the server's own selector."""
-        return self._run_cohort(
-            sel, self.selector if selector is None else selector,
-            global_params)
-
     # ------------------------------------------------------------- rounds
     def round(self) -> dict:
         if not self.runtime.speculate:
@@ -192,250 +184,164 @@ class PipelinedServer(Server):
         spec_fn = self._traced_judge_fn()
         if spec_fn is None:       # judge has no traced form: stay sequential
             return super().round()
-        return self._speculative_round(spec_fn)
+        with TraceAnnotation(spans.ROUND, round=self.round_idx):
+            return self._speculative_round(spec_fn)
+
+    def _speculate(self, spec_fn, out, cluster_ids):
+        """The device verdict on a dispatched cohort, left on the device:
+        one traced judge call, or one per cluster (clusters ascending, the
+        oracle's order) combined into one cohort mask.
+
+        Returns ``(groups, mask)``: each judged ``(rows, JudgmentResult)``
+        and the 0/1 cohort mask the speculative aggregate runs with.
+        """
+        sizes32 = out["size"].astype(jnp.float32)
+        soft32 = out["soft_label"].astype(jnp.float32)
+        if cluster_ids is None:
+            jr = spec_fn(soft32, sizes32)
+            return [(np.arange(soft32.shape[0]), jr)], jr.mask
+        cids = np.asarray(cluster_ids)
+        groups, mask = [], jnp.zeros(len(cids), jnp.float32)
+        for k in sorted(int(c) for c in np.unique(cids)):
+            rows = np.where(cids == k)[0]
+            jr = spec_fn(jnp.take(soft32, rows, axis=0),
+                         jnp.take(sizes32, rows, axis=0))
+            groups.append((rows, jr))
+            mask = mask.at[rows].set(jr.mask)
+        return groups, mask
+
+    @staticmethod
+    def _read_speculation(groups, sel):
+        """Read the device verdict back: its host mask, and the positive
+        and negative ids the throwaway selector is filed with."""
+        mask = np.zeros(len(sel), np.float32)
+        pos, neg = [], []
+        for rows, jr in groups:
+            mk = spans.fetch(jr.mask)
+            mask[rows[mk > 0]] = 1.0
+            pos.extend(sel[int(rows[i])] for i in range(len(rows))
+                       if mk[i] > 0)
+            if jr.removal_order is not None:
+                order = spans.fetch(jr.removal_order)
+                neg.extend(sel[int(rows[int(r)])] for r in order if r >= 0)
+            else:
+                # order-less judges (e.g. budgeted): index order — pools
+                # are set-based, so only the SET must match the oracle
+                # verdict
+                neg.extend(sel[int(rows[i])] for i in range(len(rows))
+                           if mk[i] == 0)
+        return mask, pos, neg
 
     def _speculative_round(self, spec_fn) -> dict:
+        """One round with the next round's cohort speculated on device.
+
+        The device verdict and aggregate of this round's cohort are
+        computed first; round t+1 is drawn on a throwaway selector copy
+        filed with that verdict and dispatched from that aggregate (on a
+        clustered server: assigned against the speculated bank — on an
+        oracle hit bitwise the one the sequential path produces). The
+        float64 oracle then judges this round; a hit adopts the speculated
+        aggregate and selector, a miss discards them and merges from the
+        oracle verdict exactly as ``Server.round``.
+        """
         # drift applies BEFORE selection, exactly as sequentially. The
         # spec_next gate below guarantees no pending dispatch ever spans a
         # drift boundary, so the corpus swap never invalidates in-flight
         # compute (and never desyncs the adopted selector stream).
         drifted = self._apply_drift()
-        if self.bank is not None:
-            return self._clustered_spec_round(spec_fn, drifted)
-        cfg = self.config
-        num = cfg.cohort_size()
-
-        if self._pending is not None:
-            sel, out = self._pending
-            self._pending = None
-            redispatched = False
-        else:
-            sel = self.selector.select(num)
-            out = self._dispatch(sel)
-            redispatched = self._redispatch_next
-        self._redispatch_next = False
-        idx = np.asarray(sel)
-        # round t+1 re-partitions some clients' data: dispatching it now
-        # would train on the PRE-drift corpus. Keep round t's verdict
-        # speculation (the aggregation overlap is still real) but skip the
-        # next-round dispatch — t+1 selects synchronously after the swap.
-        spec_next = not self._drift_at(self.round_idx + 1)
-
-        # --- device-side speculative verdict + aggregation (all async) ---
-        sizes32 = out["size"].astype(jnp.float32)
-        jr = spec_fn(out["soft_label"].astype(jnp.float32), sizes32)
-        new_global_spec = self.aggregator(self.global_params, out,
-                                          sizes32, jr.mask)
-        # state folding is mask-independent (Alg. 2): valid either way
-        new_state = self.strategy.update_state(
-            self.state, self.global_params, out, idx, cfg.num_clients)
-
-        # --- speculatively select + dispatch round t+1 on a throwaway copy
-        spec_mask = np.asarray(jr.mask)
-        spec_pos = [sel[i] for i in range(len(sel)) if spec_mask[i] > 0]
-        if jr.removal_order is not None:
-            order = np.asarray(jr.removal_order)
-            spec_neg = [sel[int(k)] for k in order if k >= 0]
-        else:
-            # order-less judges (e.g. budgeted): index order — pools are
-            # set-based, so only the SET must match the oracle verdict
-            spec_neg = [sel[i] for i in range(len(sel))
-                        if spec_mask[i] == 0]
-        # state folding is mask-independent (Alg. 2): adopt it before the
-        # speculative dispatch, which slices its client inputs from it
-        self.state = new_state
-        prefetch = getattr(self.corpus, "prefetch", None)
-        next_out = None
-        if spec_next:
-            sel_copy = copy.deepcopy(self.selector)
-            sel_copy.update(spec_pos, spec_neg)
-            next_sel = sel_copy.select(num)
-            # group assignment rides with the dispatch: sel_copy made (and,
-            # for chain strategies, grouped) this selection, so it is the
-            # selector the cohort layout is read from
-            if prefetch is None:
-                next_out = self._dispatch(next_sel, sel_copy,
-                                          new_global_spec)
-            else:
-                # streaming plane: a dispatch here would block THIS thread
-                # on the host gather + H2D upload of round t+1's cohort.
-                # Stage it on the prefetch thread instead, so the upload
-                # overlaps the oracle's block on round t's soft labels
-                # below; the dispatch itself waits for the verdict (on a
-                # hit the gathered cohort is already staged — on a miss
-                # nothing was computed against the wrong selection and only
-                # the staged buffers are thrown away). The schedule read is
-                # idempotent (`data_schedule` returns the counts fixed at
-                # select time), so the dispatch's own read below sees
-                # bit-identical counts.
-                sched = getattr(sel_copy, "data_schedule", None)
-                prefetch(np.asarray(next_sel),
-                         None if sched is None else sched(next_sel))
-
-        # --- float64 oracle on host, overlapping the in-flight compute ---
-        soft = np.asarray(out["soft_label"], np.float64)
-        sizes = np.asarray(out["size"], np.float64)
-        a_rel, r_rel, ent = self.judge(soft, sizes)
-        mask = np.zeros(len(sel), np.float32)
-        mask[a_rel] = 1.0
-
-        hit = bool(np.array_equal(mask, spec_mask))
-        if hit:
-            self.global_params = new_global_spec
-            if spec_next:
-                self.selector = sel_copy      # same verdict -> same stream
-                if next_out is None:
-                    # streaming plane: the cohort upload was prefetched
-                    # above; this dispatch consumes the staged buffers (a
-                    # hit in the prefetcher) instead of gathering
-                    # synchronously
-                    next_out = self._dispatch(next_sel, sel_copy,
-                                              new_global_spec)
-                self._pending = (next_sel, next_out)
-            else:
-                # drift boundary: no speculative t+1 exists; feed the
-                # verdict back directly (identical to the sequential call)
-                self.selector.update([sel[i] for i in a_rel],
-                                     [sel[i] for i in r_rel])
-        else:                                  # discard, redo from oracle
-            if spec_next and prefetch is not None:
-                # selector misprediction: drop the staged cohort — the
-                # re-selected round t+1 falls back to a synchronous gather
-                self.corpus.cancel_prefetch()
-            self.global_params = self.aggregator(
-                self.global_params, out,
-                jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
-            self.selector.update([sel[i] for i in a_rel],
-                                 [sel[i] for i in r_rel])
-            # a miss only forces a re-dispatch when a speculative t+1 was
-            # actually issued (at a drift boundary nothing was in flight)
-            self._redispatch_next = spec_next
-
-        pos = [sel[i] for i in a_rel]
-        neg = [sel[i] for i in r_rel]
-        comm = comm_bytes(self.global_params, len(sel), len(pos),
-                          soft.shape[-1],
-                          control_variate=self.strategy.doubles_uplink)
-        rec = {"round": self.round_idx, "selected": sel, "positive": pos,
-               "negative": neg, "entropy": ent, "comm": comm,
-               "spec_hit": hit, "redispatched": redispatched}
-        self.history.append(rec)
-        self.round_idx += 1
-        return rec
-
-    # ------------------------------------------------- clustered speculation
-    def _clustered_spec_round(self, spec_fn, drifted) -> dict:
-        """The speculative round over a K-center ModelBank.
-
-        Structure mirrors ``_speculative_round`` with three deltas: the
-        traced judge runs per cluster (masks combined over the cohort),
-        the speculative aggregation is the ``perclstr`` masked mean over
-        the bank, and the speculative NEXT assignment is computed against
-        the speculatively aggregated bank — on an oracle hit that bank is
-        bitwise the one the sequential path would have produced, so the
-        assignment (host argmin over jitted scores) is bitwise too; on a
-        miss the dispatch is discarded exactly like the unclustered path.
-        Assignment-state folding (FeSEM) is verdict-independent by
-        protocol contract and runs exactly once per round, before any
-        speculative next-round assignment reads it.
-        """
-        cfg = self.config
-        num = cfg.cohort_size()
-
+        num = self.config.cohort_size()
         if self._pending is not None:
             sel, cids, out = self._pending
             self._pending = None
             redispatched = False
         else:
-            sel = self.selector.select(num)
-            cids = self.cluster.assign(sel)
-            out = self._dispatch_banked(sel, self.selector, cids)
+            with TraceAnnotation(spans.SELECT):
+                sel = self.selector.select(num)
+                cids = (None if self.bank is None
+                        else self.cluster.assign(sel))
+            out = self._launch(sel, self.selector, cids)
             redispatched = self._redispatch_next
         self._redispatch_next = False
-        idx = np.asarray(sel)
+        # round t+1 re-partitions some clients' data: dispatching it now
+        # would train on the PRE-drift corpus. Keep round t's verdict
+        # speculation (the aggregation overlap is still real) but skip the
+        # next-round dispatch — t+1 selects synchronously after the swap.
         spec_next = not self._drift_at(self.round_idx + 1)
+        start = self.global_params
 
-        # --- per-cluster device verdict (cluster-ascending, the oracle's
-        # own order) combined into one cohort mask ---------------------
-        sizes32 = out["size"].astype(jnp.float32)
-        soft_dev = out["soft_label"].astype(jnp.float32)
-        cids_np = np.asarray(cids)
-        spec_mask = np.zeros(len(sel), np.float32)
-        spec_pos, spec_neg = [], []
-        for k in sorted(int(c) for c in np.unique(cids_np)):
-            rows = np.where(cids_np == k)[0]
-            jr = spec_fn(jnp.take(soft_dev, rows, axis=0),
-                         jnp.take(sizes32, rows, axis=0))
-            mk = np.asarray(jr.mask)
-            spec_mask[rows[mk > 0]] = 1.0
-            spec_pos.extend(sel[int(rows[i])] for i in range(len(rows))
-                            if mk[i] > 0)
-            if jr.removal_order is not None:
-                order = np.asarray(jr.removal_order)
-                spec_neg.extend(sel[int(rows[int(r)])] for r in order
-                                if r >= 0)
-            else:
-                spec_neg.extend(sel[int(rows[i])] for i in range(len(rows))
-                                if mk[i] == 0)
+        # --- device-side speculative verdict and aggregate (all async),
+        # then the verdict read back -------------------------------------
+        with TraceAnnotation(spans.JUDGE):
+            groups, spec_dev = self._speculate(spec_fn, out, cids)
+        with TraceAnnotation(spans.AGGREGATE):
+            new_spec = self._aggregate(start, out, out["size"], spec_dev,
+                                       cids)
+        with TraceAnnotation(spans.FEEDBACK):
+            # verdict-independent (Alg. 2), so valid either way: fold it
+            # before the speculative dispatch, which slices its client
+            # inputs from the state and (clustered) assigns from the
+            # sticky assignment state
+            self._fold(start, out, sel, cids)
+        bank_spec = None if self.bank is None else self.bank.replace(
+            new_spec)
+        with TraceAnnotation(spans.JUDGE):
+            spec_mask, spec_pos, spec_neg = self._read_speculation(groups,
+                                                                   sel)
 
-        out_c = dict(out)
-        out_c["cluster"] = jnp.asarray(cids_np, jnp.int32)
-        new_stacked_spec = self.aggregator(
-            self.bank.stacked, out_c, sizes32, jnp.asarray(spec_mask))
-        bank_spec = self.bank.replace(new_stacked_spec)
-        new_state = self.strategy.update_state(
-            self.state, self.bank.stacked, out, idx, cfg.num_clients)
-        # once per round, against the PRE-aggregation centers, BEFORE the
-        # speculative next assignment reads the sticky state it may mutate
-        self.cluster.update(sel, cids_np, out, self.bank)
-
-        # --- speculatively select + assign + dispatch round t+1 ---------
-        self.state = new_state
+        # --- speculatively select + dispatch round t+1 on a throwaway copy
+        prefetch = getattr(self.corpus, "prefetch", None)
+        # clustered dispatch is always eager (no prefetch deferral): the
+        # assignment itself must evaluate the cohort's data, so the gather
+        # cannot be deferred behind the oracle anyway
+        deferred = spec_next and prefetch is not None and cids is None
         next_out = None
         if spec_next:
-            sel_copy = copy.deepcopy(self.selector)
-            sel_copy.update(spec_pos, spec_neg)
-            next_sel = sel_copy.select(num)
-            next_cids = self.cluster.assign(next_sel, bank=bank_spec)
-            # clustered dispatch is always eager (no prefetch deferral):
-            # the assignment itself must evaluate the cohort's data, so
-            # the gather cannot be deferred behind the oracle anyway; on
-            # the streaming plane this trades upload overlap for the
-            # simpler invariant that a pending always holds real outputs
-            next_out = self._dispatch_banked(next_sel, sel_copy, next_cids,
-                                             bank=bank_spec)
-
-        # --- float64 per-cluster oracle on host -------------------------
-        soft = np.asarray(out["soft_label"], np.float64)
-        sizes = np.asarray(out["size"], np.float64)
-        mask, pos, neg, ent, clusters = self._judge_clusters(
-            soft, sizes, cids_np, sel)
-
-        hit = bool(np.array_equal(mask, spec_mask))
-        if hit:
-            self.bank = bank_spec
-            if spec_next:
-                self.selector = sel_copy      # same verdict -> same stream
-                self._pending = (next_sel, next_cids, next_out)
+            with TraceAnnotation(spans.SELECT):
+                sel_copy = copy.deepcopy(self.selector)
+                sel_copy.update(spec_pos, spec_neg)
+                next_sel = sel_copy.select(num)
+                next_cids = (None if cids is None else
+                             self.cluster.assign(next_sel, bank=bank_spec))
+            # group assignment rides with the dispatch: sel_copy made (and,
+            # for chain strategies, grouped) this selection, so it is the
+            # selector the cohort layout is read from
+            if deferred:
+                # streaming plane: a dispatch here would block THIS thread
+                # on the host gather + H2D upload of round t+1's cohort.
+                # Stage it on the prefetch thread instead, so the upload
+                # overlaps the oracle's block on round t's soft labels
+                # below; the dispatch itself waits for the verdict (on a
+                # miss only the staged buffers are thrown away). The
+                # schedule read is idempotent, so the dispatch's own read
+                # sees bit-identical counts.
+                sched = getattr(sel_copy, "data_schedule", None)
+                prefetch(np.asarray(next_sel),
+                         None if sched is None else sched(next_sel))
             else:
-                self.selector.update(pos, neg)
-        else:                                  # discard, redo from oracle
-            self.bank = self.bank.replace(self.aggregator(
-                self.bank.stacked, out_c,
-                jnp.asarray(sizes, jnp.float32), jnp.asarray(mask)))
-            self.selector.update(pos, neg)
-            self._redispatch_next = spec_next
-        self.global_params = self.bank.stacked
+                next_out = self._launch(next_sel, sel_copy, next_cids,
+                                        new_spec, bank_spec)
 
-        comm = comm_bytes(self.bank.center(0), len(sel), len(pos),
-                          soft.shape[-1],
-                          control_variate=self.strategy.doubles_uplink)
-        rec = {"round": self.round_idx, "selected": sel, "positive": pos,
-               "negative": neg, "entropy": ent, "comm": comm,
-               "cluster": [int(c) for c in cids_np], "clusters": clusters,
-               "spec_hit": hit, "redispatched": redispatched}
-        if drifted:
-            rec["drift"] = [list(ev.clients) for ev in drifted]
-        self.history.append(rec)
-        self.round_idx += 1
-        return rec
+        # --- float64 oracle on host, overlapping the in-flight compute ---
+        soft, sizes = self._readback(out)
+        verdict = self._verdict(soft, sizes, sel, cids)
+        hit = bool(np.array_equal(verdict[0], spec_mask))
+        if hit and spec_next:
+            self.selector = sel_copy          # same verdict -> same stream
+            if next_out is None:
+                # streaming plane: consumes the staged buffers (a hit in
+                # the prefetcher) instead of gathering synchronously
+                next_out = self._launch(next_sel, sel_copy, None, new_spec)
+            self._pending = (next_sel, next_cids, next_out)
+        elif not hit:                          # discard, redo from oracle
+            if deferred:
+                # selector misprediction: drop the staged cohort — the
+                # re-selected round t+1 falls back to a synchronous gather
+                self.corpus.cancel_prefetch()
+            # a miss only forces a re-dispatch when a speculative t+1 was
+            # actually issued (at a drift boundary nothing was in flight)
+            self._redispatch_next = spec_next
+        rec = self._merge(start, out, sizes, verdict, sel, cids,
+                          new=new_spec if hit else None, folded=True,
+                          fed=hit and spec_next, spec_hit=hit,
+                          redispatched=redispatched)
+        return self._commit(rec, drifted)

@@ -93,8 +93,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...core.aggregation import comm_bytes
 from ...core.pools import pools_draw, pools_refile
+from .. import spans
 from ..registry import register
 from ..selectors import TracedPoolSelector, UniformSelector
 from .engine import PipelinedServer, RuntimeConfig
@@ -400,37 +400,27 @@ class ScanServer(PipelinedServer):
             params_out, key_out, pos_out, neg_out, ys = self._scan_fn(r)(
                 params, key, pos, neg, seg_rows)
             self._blocks += 1
-            soft_all = np.asarray(ys["soft"], np.float64)
-            sizes_all = np.asarray(ys["size"], np.float64)
-            masks_all = np.asarray(ys["mask"])
-            sels_all = np.asarray(ys["sel"])
+            soft_all = spans.fetch(ys["soft"], np.float64)
+            sizes_all = spans.fetch(ys["size"], np.float64)
+            masks_all = spans.fetch(ys["mask"])
+            sels_all = spans.fetch(ys["sel"])
             keys_all = ys.get("key")
 
             mismatch_at = None
             for j in range(r):
                 sel = [int(c) for c in sels_all[j]]
-                a_rel, r_rel, ent = self.judge(soft_all[j], sizes_all[j])
-                oracle = np.zeros(num, np.float32)
-                oracle[a_rel] = 1.0
-                if not np.array_equal(oracle, masks_all[j]):
+                verdict = self._verdict(soft_all[j], sizes_all[j], sel)
+                if not np.array_equal(verdict[0], masks_all[j]):
                     mismatch_at = j
                     break
-                pos_ids = [sel[i] for i in a_rel]
-                neg_ids = [sel[i] for i in r_rel]
                 if pool_fold:
                     # mirror the confirmed in-scan draw, then re-file —
                     # the exact sequential select/update cycle
                     self.selector.fold_drawn(sels_all[j], keys_all[j])
-                self.selector.update(pos_ids, neg_ids)
-                comm = comm_bytes(
-                    self.global_params, len(sel), len(pos_ids),
-                    soft_all.shape[-1],
-                    control_variate=self.strategy.doubles_uplink)
-                self._ready.append({
-                    "round": base + done + j, "selected": sel,
-                    "positive": pos_ids, "negative": neg_ids,
-                    "entropy": ent, "comm": comm, "spec_hit": True,
-                    "redispatched": redispatched})
+                rec = self._record(sel, verdict, soft_all.shape[-1],
+                                   spec_hit=True, redispatched=redispatched)
+                rec["round"] = base + done + j
+                self._ready.append(rec)
 
             if mismatch_at is None:
                 params = params_out
@@ -464,37 +454,12 @@ class ScanServer(PipelinedServer):
                 # the continuation's draws chain from the carry key as it
                 # stood AFTER round j's split
                 self._key = ys["key"][j]
-            params = self._oracle_round(
-                params, sels_all[j], base + done + j)
+            # the sequential round after selection, from the rewind point
+            rec = self._play([int(c) for c in sels_all[j]], start=params,
+                             spec_hit=False, redispatched=False)
+            rec["round"] = base + done + j
+            self._ready.append(rec)
+            params = self.global_params
             done += j + 1
             redispatched = True
         self.global_params = params
-
-    def _oracle_round(self, start_params, sel, round_no: int):
-        """The sequential round, replayed eagerly for a mismatched scan
-        step: same select(ed cohort) -> ClientUpdate -> float64 oracle ->
-        aggregate sequence as ``Server.round``, from ``start_params``."""
-        cfg = self.config
-        sel = [int(c) for c in np.asarray(sel)]
-        out = self._run_cohort(sel, self.selector, start_params)
-        soft = np.asarray(out["soft_label"], np.float64)
-        sizes = np.asarray(out["size"], np.float64)
-        a_rel, r_rel, ent = self.judge(soft, sizes)
-        mask = np.zeros(len(sel), np.float32)
-        mask[a_rel] = 1.0
-        new_params = self.aggregator(
-            start_params, out, jnp.asarray(sizes, jnp.float32),
-            jnp.asarray(mask))
-        self.state = self.strategy.update_state(
-            self.state, start_params, out, np.asarray(sel),
-            cfg.num_clients)
-        pos = [sel[i] for i in a_rel]
-        neg = [sel[i] for i in r_rel]
-        self.selector.update(pos, neg)
-        comm = comm_bytes(new_params, len(sel), len(pos), soft.shape[-1],
-                          control_variate=self.strategy.doubles_uplink)
-        self._ready.append({
-            "round": round_no, "selected": sel, "positive": pos,
-            "negative": neg, "entropy": ent, "comm": comm,
-            "spec_hit": False, "redispatched": False})
-        return new_params

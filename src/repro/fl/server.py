@@ -337,142 +337,159 @@ class Server:
         the pipelined engine must not speculate across that boundary."""
         return any(ev.round == round_no for ev in self._drift)
 
-    # ---------------------------------------------------------- clustering
-    def _dispatch_banked(self, sel, selector, cluster_ids, bank=None):
-        """The clustered cohort dispatch: start params are each client's
-        assigned center, gathered off ``bank`` (the server's own unless a
+    def _launch(self, sel, selector, cluster_ids=None, start=None,
+                bank=None):
+        """Start a cohort's client compute: from ``start`` (default the
+        global model), or, given ``cluster_ids``, from each client's
+        center gathered off ``bank`` (the server's own unless a
         speculative bank is passed)."""
-        bank = self.bank if bank is None else bank
-        with TraceAnnotation(spans.STAGE):
-            start = bank.gather(cluster_ids)
+        if cluster_ids is not None:
+            bank = self.bank if bank is None else bank
+            with TraceAnnotation(spans.STAGE):
+                start = bank.gather(cluster_ids)
         return self._run_cohort(sel, selector, start)
 
-    def _judge_clusters(self, soft, sizes, cluster_ids, sel):
-        """Per-cluster judgment: the composition's judge runs on each
-        cluster's member rows independently (float64, host — the verdict
-        of record for clustered rounds).
+    # ------------------------------------------------------ the round tail
+    # Paper Alg. 2 after the client programs: readback -> verdict ->
+    # aggregate -> feedback -> record. Every engine runs these stages, so
+    # every engine opens the same ``fl.*`` spans and keeps the same record.
+    @staticmethod
+    def _readback(out):
+        """The round's two readbacks: soft labels and sizes, float64."""
+        return (spans.fetch(out["soft_label"], np.float64),
+                spans.fetch(out["size"], np.float64))
 
-        Returns ``(mask, pos, neg, entropy, clusters)`` — the combined
-        0/1 admission mask over the cohort, positive/negative client ids
-        (clusters ascending, the judge's own order within each), the
-        member-count-weighted mean of the per-cluster group entropies,
-        and the per-cluster verdict dict the history records.
+    def _verdict(self, soft, sizes, sel, cluster_ids=None):
+        """The float64 verdict of record over the cohort ``sel``.
+
+        Returns ``(mask, pos, neg, entropy, clusters)``: the 0/1 admission
+        mask, positive and negative client ids (the judge's own order),
+        the entropy and, on clustered rounds, the per-cluster verdicts
+        the history records (``None`` otherwise). Unclustered, it is one
+        judge call and its entropy. Clustered, the judge runs on each
+        cluster's member rows (clusters ascending), and the entropy is
+        the member-count-weighted mean of the clusters' entropies.
         """
-        cluster_ids = np.asarray(cluster_ids)
-        mask = np.zeros(len(sel), np.float32)
-        pos, neg, clusters = [], [], {}
-        ents = []
-        for k in sorted(int(c) for c in np.unique(cluster_ids)):
-            rows = np.where(cluster_ids == k)[0]
-            a_rel, r_rel, ent = self.judge(soft[rows], sizes[rows])
-            mask[rows[a_rel]] = 1.0
-            p = [sel[int(rows[i])] for i in a_rel]
-            n = [sel[int(rows[i])] for i in r_rel]
-            pos.extend(p)
-            neg.extend(n)
-            clusters[str(k)] = {
-                "members": [sel[int(i)] for i in rows],
-                "positive": p, "negative": n, "entropy": ent}
-            if not np.isnan(ent):
-                ents.append((len(rows), ent))
-        total = sum(n for n, _ in ents)
-        entropy = (sum(n * e for n, e in ents) / total
-                   if total else float("nan"))
-        return mask, pos, neg, entropy, clusters
-
-    def _clustered_round(self) -> dict:
-        """One clustered Alg. 2 round: assign -> per-center ClientUpdate
-        -> per-cluster judgment -> per-cluster aggregation -> feedback."""
-        cfg = self.config
-        with TraceAnnotation(spans.SELECT):
-            sel = self.selector.select(cfg.cohort_size())
-            idx = np.asarray(sel)
-            cids = self.cluster.assign(sel)
-        out = self._dispatch_banked(sel, self.selector, cids)
-
-        soft = spans.fetch(out["soft_label"], np.float64)
-        sizes = spans.fetch(out["size"], np.float64)
         with TraceAnnotation(spans.JUDGE):
-            mask, pos, neg, ent, clusters = self._judge_clusters(
-                soft, sizes, cids, sel)
+            mask = np.zeros(len(sel), np.float32)
+            if cluster_ids is None:
+                a_rel, r_rel, ent = self.judge(soft, sizes)
+                mask[a_rel] = 1.0
+                return (mask, [sel[i] for i in a_rel],
+                        [sel[i] for i in r_rel], ent, None)
+            cluster_ids = np.asarray(cluster_ids)
+            pos, neg, clusters, ents = [], [], {}, []
+            for k in sorted(int(c) for c in np.unique(cluster_ids)):
+                rows = np.where(cluster_ids == k)[0]
+                a_rel, r_rel, ent = self.judge(soft[rows], sizes[rows])
+                mask[rows[a_rel]] = 1.0
+                p = [sel[int(rows[i])] for i in a_rel]
+                n = [sel[int(rows[i])] for i in r_rel]
+                pos.extend(p)
+                neg.extend(n)
+                clusters[str(k)] = {
+                    "members": [sel[int(i)] for i in rows],
+                    "positive": p, "negative": n, "entropy": ent}
+                if not np.isnan(ent):
+                    ents.append((len(rows), ent))
+            total = sum(n for n, _ in ents)
+            entropy = (sum(n * e for n, e in ents) / total
+                       if total else float("nan"))
+            return mask, pos, neg, entropy, clusters
 
+    def _aggregate(self, start, out, weights, mask, cluster_ids=None):
+        """The aggregator over the cohort from ``start`` (the global model
+        or the K-stacked bank), with float32 weights and the mask."""
+        if cluster_ids is not None:
+            out = dict(out)
+            out["cluster"] = jnp.asarray(np.asarray(cluster_ids), jnp.int32)
+        return self.aggregator(start, out, jnp.asarray(weights, jnp.float32),
+                               jnp.asarray(mask))
+
+    def _fold(self, start, out, sel, cluster_ids=None) -> None:
+        """The verdict-independent feedback: strategy state, and the
+        clustered assignment state, both against the pre-aggregation
+        model ``start`` / bank."""
+        self.state = self.strategy.update_state(
+            self.state, start, out, np.asarray(sel), self.config.num_clients)
+        if cluster_ids is not None:
+            self.cluster.update(sel, cluster_ids, out, self.bank)
+
+    def _merge(self, start, out, weights, verdict, sel, cluster_ids=None, *,
+               new=None, folded=False, fed=False, **extra) -> dict:
+        """Aggregate and feed back one judged cohort; returns its record.
+
+        ``new`` is an aggregate already computed (a confirmed
+        speculation), ``folded`` says :meth:`_fold` already ran, and
+        ``fed`` that the selector already saw the verdict. ``extra``
+        keys follow the record's own.
+        """
         with TraceAnnotation(spans.AGGREGATE):
-            out_c = dict(out)
-            out_c["cluster"] = jnp.asarray(cids, jnp.int32)
-            new_stacked = self.aggregator(
-                self.bank.stacked, out_c,
-                jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
+            if new is None:
+                new = self._aggregate(start, out, weights, verdict[0],
+                                      cluster_ids)
         with TraceAnnotation(spans.FEEDBACK):
-            self.state = self.strategy.update_state(
-                self.state, self.bank.stacked, out, idx, cfg.num_clients)
-            # assignment state folds against the PRE-aggregation centers
-            # (verdict-independent — the speculation contract)
-            self.cluster.update(sel, cids, out, self.bank)
-            self.bank = self.bank.replace(new_stacked)
-            self.global_params = self.bank.stacked
-            self.selector.update(pos, neg)
+            if not folded:
+                self._fold(start, out, sel, cluster_ids)
+            if self.bank is None:
+                self.global_params = new
+            else:
+                self.bank = self.bank.replace(new)
+                self.global_params = self.bank.stacked
+            return self._record(sel, verdict, out["soft_label"].shape[-1],
+                                cluster_ids, fed=fed, **extra)
 
-            # uplink accounting per the paper's model: positives ship ONE
-            # model each (their own center), so the template is a single
-            # center, never the K-stacked bank
-            comm = comm_bytes(self.bank.center(0), len(sel), len(pos),
-                              soft.shape[-1],
-                              control_variate=self.strategy.doubles_uplink)
-            rec = {"round": self.round_idx, "selected": sel,
-                   "positive": pos, "negative": neg, "entropy": ent,
-                   "comm": comm, "cluster": [int(c) for c in cids],
-                   "clusters": clusters}
-            self.history.append(rec)
-            self.round_idx += 1
+    def _record(self, sel, verdict, num_classes, cluster_ids=None, *,
+                fed=False, **extra) -> dict:
+        """Selector feedback, uplink bytes and the round's record."""
+        _, pos, neg, ent, clusters = verdict
+        if not fed:
+            self.selector.update(pos, neg)
+        # uplink accounting per the paper's model: positives ship ONE
+        # model each (their own center on a clustered server, so the
+        # template is a single center, never the K-stacked bank)
+        template = (self.global_params if self.bank is None
+                    else self.bank.center(0))
+        comm = comm_bytes(template, len(sel), len(pos), num_classes,
+                          control_variate=self.strategy.doubles_uplink)
+        rec = {"round": self.round_idx, "selected": sel, "positive": pos,
+               "negative": neg, "entropy": ent, "comm": comm}
+        if clusters is not None:
+            rec["cluster"] = [int(c) for c in cluster_ids]
+            rec["clusters"] = clusters
+        rec.update(extra)
         return rec
+
+    def _commit(self, rec: dict, drifted=()) -> dict:
+        """Append the record and advance. A clustered record notes the
+        drift events applied before its round (``drift``); an unclustered
+        record keeps the paper's keys."""
+        if drifted and self.bank is not None:
+            rec["drift"] = [list(ev.clients) for ev in drifted]
+        self.history.append(rec)
+        self.round_idx += 1
+        return rec
+
+    def _play(self, sel, cluster_ids=None, start=None, **extra) -> dict:
+        """The sequential round after selection: launch the cohort from
+        ``start`` (default the global model), read back, judge, merge."""
+        start = self.global_params if start is None else start
+        out = self._launch(sel, self.selector, cluster_ids, start)
+        soft, sizes = self._readback(out)
+        verdict = self._verdict(soft, sizes, sel, cluster_ids)
+        return self._merge(start, out, sizes, verdict, sel, cluster_ids,
+                           **extra)
 
     def round(self) -> dict:
         """One paper Alg. 2 round; returns the history record. Its steps
         run under the profiler spans of :mod:`repro.fl.spans`."""
         with TraceAnnotation(spans.ROUND, round=self.round_idx):
             drifted = self._apply_drift()
-            if self.bank is not None:
-                rec = self._clustered_round()
-                if drifted:
-                    rec["drift"] = [list(ev.clients) for ev in drifted]
-                return rec
-            cfg = self.config
             with TraceAnnotation(spans.SELECT):
-                sel = self.selector.select(cfg.cohort_size())
-                idx = np.asarray(sel)
-            out = self._run_cohort(sel, self.selector)
-
-            soft = spans.fetch(out["soft_label"], np.float64)  # (|S_t|, C)
-            sizes = spans.fetch(out["size"], np.float64)
-
-            with TraceAnnotation(spans.JUDGE):
-                a_rel, r_rel, ent = self.judge(soft, sizes)
-                mask = np.zeros(len(sel), np.float32)
-                mask[a_rel] = 1.0
-
-            with TraceAnnotation(spans.AGGREGATE):
-                new_global = self.aggregator(
-                    self.global_params, out,
-                    jnp.asarray(sizes, jnp.float32), jnp.asarray(mask))
-            with TraceAnnotation(spans.FEEDBACK):
-                self.state = self.strategy.update_state(
-                    self.state, self.global_params, out, idx,
-                    cfg.num_clients)
-                self.global_params = new_global
-
-                pos = [sel[i] for i in a_rel]
-                neg = [sel[i] for i in r_rel]
-                self.selector.update(pos, neg)
-
-                comm = comm_bytes(self.global_params, len(sel), len(pos),
-                                  soft.shape[-1],
-                                  control_variate=self.strategy.doubles_uplink)
-                rec = {"round": self.round_idx, "selected": sel,
-                       "positive": pos, "negative": neg, "entropy": ent,
-                       "comm": comm}
-                self.history.append(rec)
-                self.round_idx += 1
-        return rec
+                sel = self.selector.select(self.config.cohort_size())
+                cids = (None if self.bank is None
+                        else self.cluster.assign(sel))
+            return self._commit(self._play(sel, cids), drifted)
 
     # ------------------------------------------------------------------
     def evaluate(self, x: jax.Array, y: jax.Array,

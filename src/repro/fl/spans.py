@@ -6,7 +6,7 @@ example inside ``jax.profiler.trace(log_dir)``), on the same clock as the
 device's programs in that trace. Otherwise they record nothing and cost
 0.4-0.6 us each on a TPU v5e host (timed with ``timeit``, JAX 0.9.0)::
 
-    fl.round      one Server.round(); keyword ``round`` = the round number
+    fl.round      one round() of an engine; keyword ``round`` = its number
       fl.select   the selector's draw (and the clustered assignment)
       fl.stage    the cohort gathered off the data plane, the strategy's
                   per-client inputs
@@ -21,6 +21,20 @@ device's programs in that trace. Otherwise they record nothing and cost
 Every device-to-host readback of a round goes through :func:`fetch`, so
 the number of ``fl.fetch`` spans in one ``fl.round`` is the number of
 times that round waited on the device.
+
+Every engine runs the round's tail through ``Server``'s stages
+(readback, verdict, merge), so every engine opens these spans. A
+speculative pipelined round first opens ``fl.judge`` (the device verdict
+on its own cohort), ``fl.aggregate`` (the speculative aggregate),
+``fl.feedback`` (the strategy-state fold), all dispatched before any
+readback, and ``fl.judge`` again (that verdict read back), then the
+sequence above: the next cohort's draw and dispatch, and this
+cohort's readbacks, oracle, aggregate and feedback; when the oracle
+confirms the guess, that ``fl.aggregate`` only adopts the speculative
+aggregate. An async round (one flush) opens ``fl.select`` per dispatched
+cohort and ``fl.judge`` per screened arrival batch. A scan block's
+folded rounds have no ``fl.round``: the block's readbacks are
+``fl.fetch`` spans and each confirmed round's oracle an ``fl.judge``.
 
 The mesh engine's step opens one counter span, after its readback, where
 the model has held-expert layers (:func:`route_counter`)::

@@ -98,28 +98,33 @@ def test_async_reduction_matches_sequential_golden(tiny, variant, comp):
         float(golden["params_digest"]), rel=1e-7)
 
 
-def test_async_reduction_matches_live_sequential(tiny):
-    """Same reduction against a live sequential server: histories equal and
-    params bitwise identical (same compiled program, same reduction)."""
+def test_async_reduction_matches_live_sequential(tiny, same_run):
+    """Same reduction against a live sequential server in the same
+    process: on one device every record key the sequential round writes
+    and every params leaf are equal, bit for bit (same compiled program,
+    same reduction), on any host."""
     data, params = tiny
     seq = fl.build("fedentropy", cnn.apply, params, data,
                    fl.ServerConfig(num_clients=8, participation=0.5,
                                    seed=0),
                    LocalSpec(epochs=1, batch_size=20))
     asy = _build(tiny)
-    for _ in range(3):
-        a, b = seq.round(), asy.round()
+    for _ in range(4):
+        seq.round()
+        asy.round()
+    if _SINGLE_DEVICE:
+        same_run(asy, seq)
+        return
+    # the forced multi-device mesh shards the async fan-out: verdict ints
+    # exact, floats to the multi-device tolerance
+    for a, b in zip(seq.history, asy.history):
         for k in ("round", "selected", "positive", "negative"):
             assert a[k] == b[k]
         assert a["comm"] == b["comm"]
         assert b["entropy"] == pytest.approx(a["entropy"], abs=ENT_ATOL)
     for x, y in zip(jax.tree.leaves(seq.global_params),
                     jax.tree.leaves(asy.global_params)):
-        if _SINGLE_DEVICE:
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-        else:
-            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                       atol=1e-6)
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
 
 
 # --------------------------------------------------------- straggler clock

@@ -149,6 +149,25 @@ def test_pipelined_matches_cluster_golden(tiny, speculate):
         assert all("spec_hit" in r for r in server.history)
 
 
+@pytest.mark.parametrize("speculate", [False, True])
+def test_k2_pipelined_matches_live_server(tiny, speculate, same_run):
+    """Same host, same process: K=2 clustered rounds (drift at round 2)
+    through the pipelined engine equal the sequential ``Server``'s bit
+    for bit — records, per-cluster verdicts and every bank leaf."""
+    server = _build(tiny, k=2, drift=_drift(tiny))
+    assert type(server) is fl.Server
+    engine = _build(tiny, k=2, drift=_drift(tiny), engine="pipelined",
+                    runtime=fl.RuntimeConfig(speculate=speculate,
+                                             shard=False))
+    for _ in range(5):
+        server.round()
+        engine.round()
+    same_run(engine, server)
+    assert [r["round"] for r in engine.history if "drift" in r] == [2]
+    if speculate:
+        assert all(isinstance(r["spec_hit"], bool) for r in engine.history)
+
+
 def test_pipelined_speculation_never_spans_drift(tiny):
     """No pending dispatch may exist when a drift event applies: the
     round before the drift must not speculatively dispatch (spec_next)."""

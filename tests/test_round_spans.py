@@ -70,6 +70,38 @@ def test_each_round_holds_its_steps_in_order(traced):
             assert a[1] <= b[0]
 
 
+def test_speculative_round_holds_the_same_steps(tmp_path):
+    """A speculative pipelined round opens the same spans through the
+    same round stages. It first judges and aggregates its own cohort on
+    the device and folds the strategy state (``fl.judge``,
+    ``fl.aggregate``, ``fl.feedback``, all dispatched before any
+    readback), then reads the device verdict back (``fl.judge`` with the
+    mask and removal-order readbacks inside it); then come ``Server``'s
+    steps in ``Server``'s order: the draw, staging and dispatch of the
+    next round's cohort, and this round's readbacks, oracle judgment,
+    aggregate (the confirmed speculation adopted) and feedback."""
+    pd = _trace_rounds(str(tmp_path), 2, engine="pipelined",
+                       runtime=fl.RuntimeConfig(speculate=True))
+    lo, hi = spans.window(pd)
+    threads = spans.program_spans(pd, lo, hi)
+    rounds = [(s, e) for evs in threads for s, e, n in evs
+              if n == "fl.round"]
+    assert len(rounds) == 2
+    for rs, re_ in rounds:
+        inside = [ev for evs in threads for ev in evs
+                  if rs <= ev[0] and ev[1] <= re_ and ev[2] != "fl.round"]
+        assert [n for _, _, n in inside] == [
+            "fl.judge", "fl.aggregate", "fl.feedback", "fl.judge",
+            "fl.fetch", "fl.fetch"] + CHILDREN
+        top = [ev for ev in inside
+               if not any(o is not ev and o[0] <= ev[0] and ev[1] <= o[1]
+                          for o in inside)]
+        assert [n for _, _, n in top] == [
+            "fl.judge", "fl.aggregate", "fl.feedback", "fl.judge"] + CHILDREN
+        for a, b in zip(top, top[1:]):
+            assert a[1] <= b[0]
+
+
 def test_rounds_carry_distinct_numbers(traced):
     _, pd = traced
     numbers = [dict(e.stats).get("round")

@@ -158,6 +158,35 @@ def test_misspeculation_falls_back_and_stays_correct(tiny):
         assert prev["spec_hit"] == (not prev["negative"])
 
 
+@pytest.mark.parametrize("case", ["spec-off", "spec-on", "spec-miss",
+                                  "spec-miss-streaming"])
+def test_pipelined_matches_live_server(tiny, case, same_run):
+    """Same host, same process: the pipelined engine's history and params
+    equal the sequential ``Server``'s bit for bit, with speculation off,
+    on (every guess confirmed), and forced to miss on every round with a
+    rejection — on the resident plane, and on the streaming plane, where
+    the next cohort is prefetched and a miss cancels it. Unlike the
+    recorded goldens this holds on any host."""
+    axes = {}
+    if case.startswith("spec-miss"):
+        axes["judge"] = _WrongSpeculationJudge()
+    if case.endswith("streaming"):
+        axes["data_plane"] = "streaming"
+    server = _build(tiny, engine=None, **axes)
+    assert type(server) is fl.Server
+    engine = _build(tiny, runtime=RuntimeConfig(
+        speculate=case != "spec-off", shard=False), **axes)
+    for _ in range(6):
+        server.round()
+        engine.round()
+    same_run(engine, server)
+    if case != "spec-off":
+        hits = [r["spec_hit"] for r in engine.history]
+        assert all(hits) if case == "spec-on" else not all(hits)
+    if case.endswith("streaming"):
+        assert engine.corpus.prefetch_stats()["cancelled"] > 0
+
+
 def test_speculation_with_orderless_judge_keeps_pool_population(tiny):
     """Judges whose JudgmentResult has removal_order=None (budgeted) must
     still re-file rejected devices into the pools on a speculative hit —

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import repro.fl as fl
+from repro.core.judgment import JudgmentResult
 from repro.core.strategies import LocalSpec
 from repro.data.partition import partition, stack_clients
 from repro.data.synthetic import make_image_dataset
@@ -180,6 +181,45 @@ def test_scan_forced_mismatch_truncates_and_replays(tiny):
     for r in scan.history:
         if not r["spec_hit"]:
             assert r["negative"], "only rejection rounds can misspeculate"
+
+
+class _RejectAllScanJudge(fl.MaxEntropyJudge):
+    """Oracle = real maxent; the traced form rejects everyone, so every
+    in-scan round misspeculates and the block replays it off the oracle."""
+
+    def traced(self):
+        def none_in(soft, sizes):
+            m = soft.shape[0]
+            nan = jnp.full((), jnp.nan, jnp.float32)
+            return JudgmentResult(
+                mask=jnp.zeros((m,), jnp.float32), entropy=nan,
+                initial_entropy=nan,
+                num_removed=jnp.full((), m, jnp.int32),
+                removal_order=jnp.arange(m, dtype=jnp.int32))
+        return none_in
+
+
+@pytest.mark.parametrize("mode", ["stack", "remat"])
+def test_scan_oracle_replay_matches_live_server(tiny, mode, same_run):
+    """Same host, same process: a scan engine whose every round falls
+    back to its oracle replay equals the sequential ``Server`` bit for
+    bit — records and every params leaf."""
+    data, params = tiny
+    server = fl.build("fedentropy", cnn.apply, params, data,
+                      fl.ServerConfig(num_clients=8, participation=0.5,
+                                      seed=0),
+                      LocalSpec(epochs=1, batch_size=20),
+                      selector="uniform")
+    engine = _build(tiny, "fedentropy",
+                    runtime=ScanConfig(rounds_per_scan=4, params_mode=mode,
+                                       shard=False),
+                    selector="uniform", judge=_RejectAllScanJudge())
+    assert engine.scan_rounds() == 4
+    for _ in range(4):
+        server.round()
+        engine.round()
+    same_run(engine, server)
+    assert not any(r["spec_hit"] for r in engine.history)
 
 
 # ------------------------------------------------- remat memory mode
